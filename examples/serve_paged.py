@@ -18,8 +18,7 @@ cfg = get_config("phi3-mini-3.8b", smoke=True)
 model = get_model(cfg)
 params = model.init(cfg, jax.random.PRNGKey(0))
 
-cache = PagedKVCache(cfg, PagedCacheConfig(n_pages=256, page_size=4,
-                                           interpret=True))
+cache = PagedKVCache(cfg, PagedCacheConfig(n_pages=256, page_size=4))
 loop = ServeLoop(cfg, cache, ServeConfig(max_batch=4, frag_threshold=0.2))
 
 rng = np.random.default_rng(0)

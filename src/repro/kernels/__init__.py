@@ -7,8 +7,9 @@
 * gc_compact — run-coalesced live-page copy (the paper's adaptive
   readahead adapted to HBM, DESIGN.md §2).
 
-Kernels are validated in interpret mode on CPU against ``ref.py``; on
-real TPUs ``ops.*(use_pallas=True)`` swaps them in.
+Kernels are validated in interpret mode on CPU against ``ref.py`` and
+compiled ahead of time for a described TPU v5e in the tests;
+``ops.*(use_pallas=True)`` runs them compiled on a TPU.
 """
 
 from . import ops, ref

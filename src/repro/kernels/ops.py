@@ -1,14 +1,16 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``use_pallas`` selects the kernel (TPU, or interpret mode for tests) vs
-the pure-jnp reference — the model code and the dry-run lower the
-reference path on CPU; on TPU hardware the kernels slot in unchanged.
+``use_pallas`` selects the kernel vs the pure-jnp reference.  Whether a
+kernel runs compiled or in the Pallas interpreter is decided here, from
+the platform (``interpret_mode``): compiled on the accelerator, the
+interpreter only where JAX runs on the CPU (tests, rehearsals).
 """
 
 from __future__ import annotations
 
-from typing import List, Tuple
+from typing import List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -19,25 +21,36 @@ from .paged_attention import paged_attention as _paged
 from .ssd_scan import ssd_scan as _ssd
 
 
+def interpret_mode(interpret: Optional[bool] = None) -> bool:
+    """True where Pallas kernels must run in the interpreter: JAX's default
+    platform is the CPU.  An explicit ``interpret`` (tests) wins."""
+    if interpret is not None:
+        return interpret
+    return jax.devices()[0].platform == "cpu"
+
+
 def attention(q, k, v, causal: bool = True, use_pallas: bool = False,
-              interpret: bool = False):
+              interpret: Optional[bool] = None):
     if use_pallas:
-        return _flash(q, k, v, causal=causal, interpret=interpret)
+        return _flash(q, k, v, causal=causal,
+                      interpret=interpret_mode(interpret))
     return ref.flash_attention_ref(q, k, v, causal=causal)
 
 
 def decode_attention(q, k_pool, v_pool, page_table, lengths,
-                     use_pallas: bool = False, interpret: bool = False):
+                     use_pallas: bool = False,
+                     interpret: Optional[bool] = None):
     if use_pallas:
         return _paged(q, k_pool, v_pool, page_table, lengths,
-                      interpret=interpret)
+                      interpret=interpret_mode(interpret))
     return ref.paged_attention_ref(q, k_pool, v_pool, page_table, lengths)
 
 
 def ssd(x, dt, a, bmat, cmat, chunk: int = 128, use_pallas: bool = False,
-        interpret: bool = False):
+        interpret: Optional[bool] = None):
     if use_pallas:
-        return _ssd(x, dt, a, bmat, cmat, chunk=chunk, interpret=interpret)
+        return _ssd(x, dt, a, bmat, cmat, chunk=chunk,
+                    interpret=interpret_mode(interpret))
     return ref.ssd_scan_ref(x, dt, a, bmat, cmat)
 
 
@@ -88,7 +101,8 @@ def compact_plan(valid: np.ndarray, block_pages: int
 
 
 def compact_pages(pool, valid, block_pages: int = 4,
-                  use_pallas: bool = False, interpret: bool = False):
+                  use_pallas: bool = False,
+                  interpret: Optional[bool] = None):
     """Compact live pages to the front of a fresh pool, run-coalesced.
 
     Returns (packed_pages, new_index, dma_count) where ``new_index[i]`` is
@@ -100,6 +114,7 @@ def compact_pages(pool, valid, block_pages: int = 4,
         packed, new_index = ref.compact_pages_ref(pool, jnp.asarray(valid_np))
         return packed, new_index, int(valid_np.sum())
     blocks, tail, runs = compact_plan(valid_np, block_pages)
+    interpret = interpret_mode(interpret)
     parts = []
     if len(blocks):
         parts.append(gather_page_blocks(pool, jnp.asarray(blocks),

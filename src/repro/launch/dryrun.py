@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell:
@@ -12,17 +9,21 @@ For each cell:
   * derive the three roofline terms (DESIGN.md §7),
   * write one JSON artifact per cell under artifacts/dryrun/.
 
+The dry-run is a host-only compile tool: it pins JAX to the CPU with 512
+host devices before JAX initialises, and never takes an accelerator.
+
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch olmo-1b \
       --shape train_4k [--multi-pod] [--all] [--out artifacts/dryrun]
 """
 
-import argparse      # noqa: E402
-import json          # noqa: E402
-import re            # noqa: E402
-import sys           # noqa: E402
-import time          # noqa: E402
-import traceback     # noqa: E402
+import argparse
+import json
+import os
+import re
+import sys
+import time
+import traceback
 
 # v5e hardware constants (per chip)
 PEAK_FLOPS = 197e12          # bf16
@@ -39,23 +40,12 @@ _DTYPE_BYTES = {"f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4,
                 "s8": 1, "u8": 1, "pred": 1, "s64": 8, "f64": 8}
 
 
-def _cost_dict(cost) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` output to one flat dict.
-
-    Older JAX returns a dict; newer releases return a list of
-    per-computation dicts — sum the numeric entries across them."""
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        merged: dict = {}
-        for d in cost:
-            for k, v in (d or {}).items():
-                try:
-                    merged[k] = merged.get(k, 0.0) + float(v)
-                except (TypeError, ValueError):
-                    merged.setdefault(k, v)
-        return merged
-    return dict(cost)
+def _host_devices(n: int = 512) -> None:
+    """Compile on ``n`` CPU host devices; must run before JAX initialises
+    its backends (the production meshes need 256 and 512 devices)."""
+    os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
+    import jax
+    jax.config.update("jax_platforms", "cpu")
 
 
 def collective_bytes(hlo_text: str) -> dict:
@@ -116,7 +106,7 @@ def _compile_cell(cfg, shape: str, mesh, rules, train_overrides=None):
         jitted = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
                          donate_argnums=donate)
         compiled = jitted.lower(*abstract).compile()
-    cost = _cost_dict(compiled.cost_analysis())
+    cost = compiled.cost_analysis() or {}
     coll = collective_bytes(compiled.as_text())
     return compiled, cost, coll
 
@@ -159,6 +149,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str,
              rules_name: str = "default", extra_tag: str = "",
              train_overrides: dict = None, cfg_overrides: dict = None,
              rules_updates: dict = None) -> dict:
+    _host_devices()
     from repro.configs import get_config
     from repro.launch.mesh import make_production_mesh
     from repro.launch.shapes import SHAPES, skip_reason
@@ -261,6 +252,7 @@ def main() -> None:
     ap.add_argument("--out", default="artifacts/dryrun")
     args = ap.parse_args()
 
+    _host_devices()
     from repro.configs import ARCHS
     from repro.launch.shapes import SHAPES, cells
 

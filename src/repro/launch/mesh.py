@@ -1,6 +1,6 @@
 """Production mesh construction (spec-mandated shapes).
 
-A FUNCTION, not a module-level constant — importing this module never
+Functions, not module-level constants: importing this module never
 touches jax device state (the dry-run sets XLA_FLAGS before any jax
 initialization; tests run on 1 device).
 """
@@ -8,16 +8,24 @@ initialization; tests run on 1 device).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes, devices=None):
+    # Auto axes: the model code places activations with
+    # ``with_sharding_constraint``, which only refers to Auto axes.
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
-def make_host_mesh(model: int = 1):
-    """Tiny mesh over the real local devices (tests / examples)."""
-    n = len(jax.devices())
+def make_host_mesh(model: int = 1, devices: int = 0):
+    """Mesh over the first ``devices`` local devices (0 = all of them)."""
+    n = devices or len(jax.devices())
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _mesh((n // model, model), ("data", "model"), jax.devices()[:n])

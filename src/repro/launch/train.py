@@ -11,15 +11,20 @@ production mesh — the dry-run compiles the identical step):
 * elastic resume: checkpoints store full (unsharded) tensors — a restart
   on a different mesh re-shards on load (``restore(like=...)``).
 
+Parameters and optimizer state are created by one jitted program with the
+step's own shardings, so on a data mesh each device holds only its share
+from the start.
+
 Usage:
   PYTHONPATH=src python -m repro.launch.train --arch olmo-1b --smoke \
-      --steps 20 --batch 8 --seq 128 --ckpt-dir /tmp/ckpt [--resume]
-      [--fail-at 7]
+      --steps 20 --batch 8 --seq 128 --ckpt-dir ckpt [--resume]
+      [--fail-at 7] [--layers 2] [--devices 1]
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -28,8 +33,9 @@ from ..checkpoint import CheckpointConfig, CheckpointStore
 from ..configs import get_config
 from ..models import get_model
 from ..train.data import synthetic_batch
-from ..train.optimizer import AdamWConfig
+from ..train.optimizer import AdamWConfig, init_state
 from ..train.step import TrainConfig, build_train_step
+from .compile_cache import use_compile_cache
 from .mesh import make_host_mesh
 
 
@@ -46,10 +52,18 @@ def main(argv=None) -> int:
     ap.add_argument("--fail-at", type=int, default=None,
                     help="simulate a crash after this step")
     ap.add_argument("--straggler-factor", type=float, default=2.0)
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the model to this many layers (0 = all)")
+    ap.add_argument("--devices", type=int, default=0,
+                    help="data-parallel mesh over this many devices "
+                         "(0 = all local devices)")
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
-    mesh = make_host_mesh()
+    if args.layers:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
+    mesh = make_host_mesh(devices=args.devices)
     tc = TrainConfig(adamw=AdamWConfig(lr=1e-3))
     fn, in_sh, out_sh, abstract = build_train_step(
         cfg, mesh, args.batch, args.seq, tc)
@@ -57,14 +71,19 @@ def main(argv=None) -> int:
                        donate_argnums=(0, 1))
 
     model = get_model(cfg)
-    params = model.init(cfg, jax.random.PRNGKey(0))
-    from ..train.optimizer import init_state
-    opt = init_state(params, tc.adamw)
+
+    def init(key):
+        params = model.init(cfg, key)
+        return params, init_state(params, tc.adamw)
+
+    params, opt = jax.jit(init, out_shardings=in_sh[:2])(
+        jax.random.PRNGKey(0))
     start_step = 0
 
     store = None
     if args.ckpt_dir:
-        store = CheckpointStore(args.ckpt_dir, CheckpointConfig(keep_last=2))
+        store = CheckpointStore(args.ckpt_dir, CheckpointConfig(keep_last=2),
+                                recover=args.resume)
         if args.resume:
             step, state = store.restore(like={"params": params, "opt": opt})
             if step is not None:
@@ -82,8 +101,10 @@ def main(argv=None) -> int:
         dt = time.perf_counter() - t0
         ewma = dt if ewma is None else 0.8 * ewma + 0.2 * dt
         straggler = dt > args.straggler_factor * ewma and step > start_step
-        print(f"step={step} loss={loss:.4f} dt={dt * 1e3:.0f}ms"
-              + (" STRAGGLER" % () if straggler else ""), flush=True)
+        print(f"step={step} loss={loss:.4f} "
+              f"grad_norm={float(metrics['grad_norm']):.6g} "
+              f"dt={dt * 1e3:.0f}ms"
+              + (" STRAGGLER" if straggler else ""), flush=True)
         if store and (step + 1) % args.ckpt_every == 0:
             store.save(step, {"params": params, "opt": opt},
                        extra={"loss": loss})

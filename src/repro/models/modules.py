@@ -50,7 +50,11 @@ def materialize(tree, rng: Optional[jax.Array], abstract: bool,
         if abstract:
             out.append(jax.ShapeDtypeStruct(spec.shape, param_dtype))
         else:
-            fan_in = spec.shape[0] if spec.shape else 1
+            # stacked layers share one spec: the fan-in is the first
+            # dimension after the stacking axes
+            dims = [n for n, ax in zip(spec.shape, spec.axes)
+                    if ax not in ("layers", "layers2")]
+            fan_in = dims[0] if dims else 1
             std = spec.scale / math.sqrt(max(1, fan_in))
             out.append(std * jax.random.normal(keys[i], spec.shape,
                                                param_dtype))

@@ -19,7 +19,7 @@ with the paper's pressure arithmetic (serving/scheduler.py).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,8 +34,8 @@ class PagedCacheConfig:
     n_pages: int
     page_size: int = 16
     compact_block_pages: int = 4
-    use_pallas: bool = False       # True on TPU; interpret in tests
-    interpret: bool = True
+    use_pallas: bool = True
+    interpret: Optional[bool] = None   # None: ops.interpret_mode decides
 
 
 class PagedKVCache:

@@ -31,6 +31,7 @@ def test_train_crash_resume_replays_identically(tmp_path):
     r2 = subprocess.run(base + ["--resume"], env=ENV, capture_output=True,
                         text=True, timeout=600)
     assert "training done" in r2.stdout, r2.stdout + r2.stderr
+    assert "resumed from step" in r2.stdout, r2.stdout
     second = _losses(r2.stdout)
     # resumed steps replay the uninterrupted trajectory exactly
     for step, loss in second.items():
@@ -54,5 +55,5 @@ def test_space_cap_stalls_and_gc_frees():
 
 def test_serve_driver_main():
     from repro.launch.serve import main
-    assert main(["--requests", "6", "--pages", "64",
+    assert main(["--smoke", "--requests", "6", "--pages", "64",
                  "--max-batch", "2"]) == 0
