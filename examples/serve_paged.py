@@ -46,6 +46,5 @@ def decode_fn(seq_ids):
 loop.run(decode_fn, max_steps=2000)
 print(f"completed={len(loop.done)} decode_steps={loop.decode_steps} "
       f"compactions={loop.compaction_steps} "
-      f"compaction_dmas={cache.compaction_dmas} "
       f"fragmentation={cache.fragmentation():.3f}")
 assert len(loop.done) == 16

@@ -113,7 +113,15 @@ def compact_pages(pool, valid, block_pages: int = 4,
     if not use_pallas:
         packed, new_index = ref.compact_pages_ref(pool, jnp.asarray(valid_np))
         return packed, new_index, int(valid_np.sum())
-    blocks, tail, runs = compact_plan(valid_np, block_pages)
+    return pack_pages(pool, compact_plan(valid_np, block_pages),
+                      block_pages, interpret)
+
+
+def pack_pages(pool, plan, block_pages: int = 4,
+               interpret: Optional[bool] = None):
+    """The Pallas path of :func:`compact_pages`, for a ``plan`` that
+    :func:`compact_plan` has already made; same return values."""
+    blocks, tail, _ = plan
     interpret = interpret_mode(interpret)
     parts = []
     if len(blocks):

@@ -3,11 +3,17 @@ Scavenger+-style page GC, end to end at a model's full width.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --arch olmo-1b \
-      --requests 24 [--pages 256] [--frag-threshold 0.2] [--smoke] [--check]
+      --requests 24 [--pages 256] [--frag-threshold 0.2] [--smoke] [--check] \
+      [--trace DIR]
 
 The driver reports the scheduling split between decode and compaction
-iterations and the run-coalescing DMA statistics — the serving-tier
-analog of the paper's Fig. 19/20 resource-efficiency story.
+iterations — the serving-tier analog of the paper's Fig. 19/20
+resource-efficiency story.
+
+``--trace DIR`` runs the loop under ``jax.profiler.trace(DIR)`` and, after
+the summary line, prints what ``repro.obs.spans`` recorded as one JSON
+line: each ``serve.*`` span's calls, total and self milliseconds and
+parents, and the ``sched.*`` and ``cache.*`` counters.
 
 ``--check`` verifies the served path as it runs: the first decode step's
 kernel attention against the float32 jnp reference, every live page's
@@ -18,6 +24,8 @@ kernels run compiled — that their programs hold a ``tpu_custom_call``.
 from __future__ import annotations
 
 import argparse
+import contextlib
+import json
 import time
 
 import jax
@@ -29,6 +37,7 @@ from ..kernels import ops, ref
 from ..kernels.gc_compact import gather_page_blocks
 from ..kernels.paged_attention import paged_attention
 from ..models import get_model
+from ..obs import spans
 from ..serving import (PagedCacheConfig, PagedKVCache, Request, ServeConfig,
                        ServeLoop)
 from .compile_cache import use_compile_cache
@@ -46,12 +55,12 @@ class _CheckedCache(PagedKVCache):
 
     def compact(self) -> int:
         before = _live_pages(self)
-        dmas = super().compact()
+        relocated = super().compact()
         after = _live_pages(self)
         assert before.keys() == after.keys()
         for s in before:
             assert np.array_equal(before[s], after[s]), f"seq {s} changed"
-        return dmas
+        return relocated
 
 
 def _check_attention(cache: PagedKVCache, seq_ids, q, out) -> float:
@@ -92,6 +101,9 @@ def main(argv=None) -> int:
     ap.add_argument("--frag-threshold", type=float, default=0.2)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--check", action="store_true")
+    ap.add_argument("--trace", default=None, metavar="DIR",
+                    help="profile the loop into DIR and print the spans "
+                         "and counters it recorded as one JSON line")
     args = ap.parse_args(argv)
 
     use_compile_cache()
@@ -132,17 +144,20 @@ def main(argv=None) -> int:
                 checks.append("kernels=tpu_custom_call")
 
     t0 = time.perf_counter()
-    loop.run(decode_fn, max_steps=5000)
+    with (jax.profiler.trace(args.trace) if args.trace
+          else contextlib.nullcontext()):
+        loop.run(decode_fn, max_steps=5000)
     wall = time.perf_counter() - t0
     p = loop.pressures()
     print(f"completed={len(loop.done)}/{args.requests} "
           f"decode_steps={loop.decode_steps} "
           f"compaction_steps={loop.compaction_steps} "
-          f"compaction_dmas={cache.compaction_dmas} "
           f"alloc_failures={cache.alloc_failures} "
           f"frag={cache.fragmentation():.3f} "
           f"pressures=(admit={p['admit']:.2f},frag={p['frag']:.2f}) "
           f"wall={wall:.1f}s", flush=True)
+    if args.trace:
+        print(json.dumps(spans.snapshot()), flush=True)
     if args.check:
         assert loop.compaction_steps > 0, "no compaction ran"
         print("checks passed: " + " ".join(

@@ -27,6 +27,10 @@ Four pieces (see ROADMAP "Observability"):
   components == device footprint, cache quotas == budget, monotone
   ledger windows, exemplar shares == latency) on every metrics
   snapshot, returning structured :class:`AuditViolation` reports.
+* :mod:`repro.obs.spans` — host spans and counters of the JAX tier
+  (serve loop, paged KV cache, page GC), recorded only while a
+  ``jax.profiler`` session is on and written into the profiler's own
+  trace.  Imported on first use, so the store tier never imports JAX.
 * CLIs — ``python -m repro.obs.report`` (text dashboard from a metrics
   snapshot, including p99 attribution), ``python -m repro.obs.lint``
   (trace validity lint incl. flow pairing and op-track nesting) and
@@ -55,4 +59,12 @@ __all__ = [
     "audit_document",
     "audit_snapshot",
     "lint_events",
+    "spans",
 ]
+
+
+def __getattr__(name):
+    if name == "spans":
+        import importlib
+        return importlib.import_module(".spans", __name__)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -14,6 +14,10 @@ Mapping of the paper's structures onto the serving tier:
 Compaction keeps live pages dense at the front of the pool so admission
 of long prompts never fails on fragmentation; the scheduler triggers it
 with the paper's pressure arithmetic (serving/scheduler.py).
+
+While a profiler session is on, the write, attend and compact paths
+record ``serve.cache.*`` spans and ``cache.*`` counters through
+:mod:`repro.obs.spans`.
 """
 
 from __future__ import annotations
@@ -27,6 +31,7 @@ import numpy as np
 
 from ..kernels import ops
 from ..models.config import ModelConfig
+from ..obs import spans
 
 
 @dataclasses.dataclass
@@ -52,7 +57,6 @@ class PagedKVCache:
         self.lengths: Dict[int, int] = {}
         self.frozen: Dict[int, bool] = {}           # cold sequences
         self.compactions = 0
-        self.compaction_dmas = 0
         self.alloc_failures = 0
 
     # -- space accounting (paper eq. 5 analog) ---------------------------
@@ -112,32 +116,40 @@ class PagedKVCache:
     # -- device-side views -------------------------------------------------
     def page_table_array(self, seq_ids: List[int]) -> Tuple[jax.Array,
                                                             jax.Array]:
-        max_pages = max((len(self.tables[s]) for s in seq_ids), default=1)
-        pt = np.full((len(seq_ids), max_pages), -1, np.int32)
-        ln = np.zeros((len(seq_ids),), np.int32)
-        for i, s in enumerate(seq_ids):
-            pages = self.tables[s]
-            pt[i, :len(pages)] = pages
-            ln[i] = self.lengths[s]
-        return jnp.asarray(pt), jnp.asarray(ln)
+        with spans.span("serve.cache.page_table"):
+            max_pages = max((len(self.tables[s]) for s in seq_ids),
+                            default=1)
+            pt = np.full((len(seq_ids), max_pages), -1, np.int32)
+            ln = np.zeros((len(seq_ids),), np.int32)
+            for i, s in enumerate(seq_ids):
+                pages = self.tables[s]
+                pt[i, :len(pages)] = pages
+                ln[i] = self.lengths[s]
+            return jnp.asarray(pt), jnp.asarray(ln)
 
     def write_token_kv(self, layer: int, seq_id: int, k, v) -> None:
         """Write one token's K/V (kvH, hd) into the page pool."""
         pos = self.lengths[seq_id] - 1
         page = self.tables[seq_id][pos // self.pc.page_size]
         slot = pos % self.pc.page_size
-        self.pool = self.pool.at[layer, 0, page, slot].set(
-            k.astype(self.pool.dtype))
-        self.pool = self.pool.at[layer, 1, page, slot].set(
-            v.astype(self.pool.dtype))
+        on = spans.on()
+        with (spans.Span("serve.cache.kv_write", seq=seq_id, page=page,
+                         slot=slot) if on else spans.OFF):
+            self.pool = self.pool.at[layer, 0, page, slot].set(
+                k.astype(self.pool.dtype))
+            self.pool = self.pool.at[layer, 1, page, slot].set(
+                v.astype(self.pool.dtype))
+        if on:
+            spans.count("cache.kv_rows_written", 2)
 
     def attend(self, layer: int, seq_ids: List[int], q) -> jax.Array:
         """Decode attention for the given sequences via the paged kernel.
         q: (B, H, hd) → (B, H, hd)."""
-        pt, ln = self.page_table_array(seq_ids)
-        return ops.decode_attention(
-            q, self.pool[layer, 0], self.pool[layer, 1], pt, ln,
-            use_pallas=self.pc.use_pallas, interpret=self.pc.interpret)
+        with spans.span("serve.cache.attend"):
+            pt, ln = self.page_table_array(seq_ids)
+            return ops.decode_attention(
+                q, self.pool[layer, 0], self.pool[layer, 1], pt, ln,
+                use_pallas=self.pc.use_pallas, interpret=self.pc.interpret)
 
     # -- GC: run-coalesced compaction (paper III-B.4 on HBM) ---------------
     def compact(self) -> int:
@@ -146,31 +158,53 @@ class PagedKVCache:
         Hot/cold placement (paper III-B.3): ACTIVE sequences' pages are
         packed before FROZEN ones, so the hot region stays dense and the
         next compaction touches mostly-cold long-lived pages.
-        Returns the number of copy DMAs issued (coalescing metric)."""
-        valid = np.zeros(self.pc.n_pages, bool)
-        for s, pages in self.tables.items():
-            for p in pages:
-                valid[p] = True
-        total_dmas = 0
-        # pool layout is (L, 2, P, ...): compact each (layer, kv) plane
-        # with the same mapping — compute the plan once.
-        _, new_index, dmas = ops.compact_pages(
-            self.pool[0, 0].reshape(self.pc.n_pages, self.pc.page_size, -1),
-            valid, block_pages=self.pc.compact_block_pages,
-            use_pallas=self.pc.use_pallas, interpret=self.pc.interpret)
-        new_index = np.asarray(new_index)
-        total_dmas = dmas * self.cfg.n_layers * 2
-        # apply the same permutation to the full pool in one gather
-        perm = np.arange(self.pc.n_pages)
-        for old, new in enumerate(new_index):
-            if new >= 0:
-                perm[new] = old
-        self.pool = self.pool[:, :, jnp.asarray(perm)]
-        # rewrite tables + free list
-        for s in self.tables:
-            self.tables[s] = [int(new_index[p]) for p in self.tables[s]]
-        n_live = int(valid.sum())
-        self.free = list(range(self.pc.n_pages - 1, n_live - 1, -1))
+        Returns the pages relocated: live pages whose index changed, times
+        the planes (layers x K/V) that hold them."""
+        planes = self.cfg.n_layers * 2
+        bp = self.pc.compact_block_pages
+        with spans.span("serve.cache.compact"):
+            with spans.span("serve.cache.compact.plan"):
+                valid = np.zeros(self.pc.n_pages, bool)
+                for s, pages in self.tables.items():
+                    for p in pages:
+                        valid[p] = True
+                plan = ops.compact_plan(valid, bp) if self.pc.use_pallas \
+                    else None
+            # pool layout is (L, 2, P, ...): compact layer 0's K plane for
+            # the mapping, then move every plane with it.  ``copied`` counts
+            # the pages each gather's index asks for.
+            with spans.span("serve.cache.compact.kernel"):
+                plane = self.pool[0, 0].reshape(self.pc.n_pages,
+                                                self.pc.page_size, -1)
+                if plan is None:        # the reference gathers every page
+                    _, new_index, _ = ops.compact_pages(plane, valid)
+                    copied = plane.shape[0]
+                else:
+                    _, new_index, _ = ops.pack_pages(
+                        plane, plan, bp, interpret=self.pc.interpret)
+                    blocks, tail, _ = plan
+                    copied = len(blocks) * bp + len(tail)
+                new_index = np.asarray(new_index)
+            # apply the same permutation to the full pool in one gather
+            with spans.span("serve.cache.compact.permute"):
+                perm = np.arange(self.pc.n_pages)
+                for old, new in enumerate(new_index):
+                    if new >= 0:
+                        perm[new] = old
+                perm = jnp.asarray(perm)
+                self.pool = self.pool[:, :, perm]
+                copied += perm.size * self.pool.shape[0] * self.pool.shape[1]
+            # rewrite tables + free list
+            with spans.span("serve.cache.compact.tables"):
+                for s in self.tables:
+                    self.tables[s] = [int(new_index[p])
+                                      for p in self.tables[s]]
+                live = np.flatnonzero(valid)
+                n_live = len(live)
+                self.free = list(range(self.pc.n_pages - 1, n_live - 1, -1))
+                relocated = int(np.count_nonzero(new_index[live] != live))
         self.compactions += 1
-        self.compaction_dmas += total_dmas
-        return total_dmas
+        spans.count("cache.compact.pages_live", n_live)
+        spans.count("cache.compact.pages_relocated", relocated * planes)
+        spans.count("cache.compact.pages_copied", copied)
+        return relocated * planes

@@ -11,6 +11,9 @@ When ``P_frag/(P_frag+P_admit)`` crosses the configured share, the loop
 spends an iteration on page compaction instead of decode — exactly eq. 6
 with "threads" replaced by step budget.  A rate cap (paper III-D.2)
 bounds compaction frequency so decode latency is not starved.
+
+While a profiler session is on, each iteration records ``serve.sched.*``
+spans and ``sched.*`` counters through :mod:`repro.obs.spans`.
 """
 
 from __future__ import annotations
@@ -20,6 +23,7 @@ from collections import deque
 from typing import Deque, Dict, List, Optional
 
 from ..models.config import ModelConfig
+from ..obs import spans
 from .kvcache import PagedKVCache
 
 
@@ -80,39 +84,48 @@ class ServeLoop:
         while self.queue and len(self.active) < self.sc.max_batch:
             r = self.queue[0]
             if not self.cache.add_sequence(r.rid, r.prompt_len):
+                spans.count("sched.admit_blocked")
                 break
             self.queue.popleft()
             self.active[r.rid] = r
             n += 1
+        spans.count("sched.admitted", n)
         return n
 
     def step(self, decode_fn) -> Dict[str, float]:
         """One engine iteration: maybe compact, admit, decode one token
         for every active sequence via ``decode_fn(seq_ids)``."""
-        if self.should_compact():
-            self.cache.compact()
-            self.compaction_steps += 1
-            self._since_compaction = 0
-            return {"kind": 1.0}
-        self.admit()
-        seq_ids = list(self.active.keys())
-        if seq_ids:
-            ok_ids = [s for s in seq_ids if self.cache.append_token(s)]
-            if ok_ids:
-                decode_fn(ok_ids)
-            finished = []
-            for s in ok_ids:
-                r = self.active[s]
-                r.generated += 1
-                if r.generated >= r.max_new_tokens:
-                    finished.append(s)
-            for s in finished:
-                self.cache.finish_sequence(s)
-                self.done.append(s)
-                del self.active[s]
-        self.decode_steps += 1
-        self._since_compaction += 1
-        return {"kind": 0.0}
+        with spans.span("serve.sched.step"):
+            if self.should_compact():
+                self.cache.compact()
+                self.compaction_steps += 1
+                self._since_compaction = 0
+                spans.count("sched.compactions")
+                return {"kind": 1.0}
+            self.admit()
+            seq_ids = list(self.active.keys())
+            ok_ids: List[int] = []
+            if seq_ids:
+                ok_ids = [s for s in seq_ids if self.cache.append_token(s)]
+                if ok_ids:
+                    with spans.span("serve.sched.decode"):
+                        decode_fn(ok_ids)
+                finished = []
+                for s in ok_ids:
+                    r = self.active[s]
+                    r.generated += 1
+                    if r.generated >= r.max_new_tokens:
+                        finished.append(s)
+                for s in finished:
+                    self.cache.finish_sequence(s)
+                    self.done.append(s)
+                    del self.active[s]
+            self.decode_steps += 1
+            self._since_compaction += 1
+            spans.count("sched.decode_iterations")
+            spans.count("sched.batch_slots", self.sc.max_batch)
+            spans.count("sched.decode_seqs", len(ok_ids))
+            return {"kind": 0.0}
 
     def run(self, decode_fn, max_steps: int = 10000) -> None:
         steps = 0

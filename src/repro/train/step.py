@@ -3,7 +3,9 @@
 ``build_train_step`` returns (fn, in_shardings, out_shardings, abstract
 inputs) ready for ``jax.jit(...).lower(...)`` — the dry-run consumes
 exactly this.  Gradient accumulation (microbatching) runs as a
-``lax.scan`` over global-batch splits; buffers are donated.
+``lax.scan`` over global-batch splits; buffers are donated.  The forward,
+loss and backward run under the name scope ``train.loss`` and the AdamW
+update under ``train.optimizer``, so a profile's op lines split the step.
 """
 
 from __future__ import annotations
@@ -83,8 +85,9 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, global_batch: int,
 
             def body(carry, i):
                 acc = carry
-                loss, g = jax.value_and_grad(model.loss_fn)(
-                    params, micro(i), cfg)
+                with jax.named_scope("train.loss"):
+                    loss, g = jax.value_and_grad(model.loss_fn)(
+                        params, micro(i), cfg)
                 return jax.tree.map(jnp.add, acc,
                                     {"g": g, "loss": loss}), None
 
@@ -96,10 +99,12 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, global_batch: int,
             grads = jax.tree.map(lambda g: g / tc.microbatches, acc["g"])
             loss = acc["loss"] / tc.microbatches
         else:
-            loss, grads = jax.value_and_grad(model.loss_fn)(
-                params, batch, cfg)
-        new_params, new_opt = apply_updates(params, grads, opt_state,
-                                            tc.adamw)
+            with jax.named_scope("train.loss"):
+                loss, grads = jax.value_and_grad(model.loss_fn)(
+                    params, batch, cfg)
+        with jax.named_scope("train.optimizer"):
+            new_params, new_opt = apply_updates(params, grads, opt_state,
+                                                tc.adamw)
         metrics = {"loss": loss,
                    "grad_norm": jnp.sqrt(sum(
                        jnp.sum(jnp.square(g.astype(jnp.float32)))
