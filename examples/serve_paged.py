@@ -1,5 +1,5 @@
 """Serving example: batched requests through the paged KV-cache with
-Scavenger+-style page GC (run-coalesced compaction, pressure-driven
+Scavenger+-style page GC (live-page compaction, pressure-driven
 scheduling), using a real reduced model end to end.
 
 Run:  PYTHONPATH=src python examples/serve_paged.py

@@ -10,6 +10,10 @@ The host (``ops.compact_plan``) turns the valid bitmap into a run-coalesced
 copy plan at a fixed block granularity; the kernel is a pure data-mover
 whose BlockSpec index map dereferences the scalar-prefetched source-block
 ids — each grid step is exactly one (block_pages · page · D) DMA.
+
+``move_pages`` is the mover the serving cache's compaction runs: it moves
+only the pages whose index changes, every layer's K and V plane at once,
+in place in the donated pool.
 """
 
 from __future__ import annotations
@@ -57,3 +61,39 @@ def gather_page_blocks(pool, src_block_ids, block_pages: int = 1,
         interpret=interpret,
     )(src_block_ids.astype(jnp.int32), pool_b)
     return out.reshape(m * block_pages, page, d)
+
+
+def _move_kernel(src_ref, dst_ref, page_ref, out_ref):
+    out_ref[...] = page_ref[...]
+
+
+@functools.partial(jax.jit, donate_argnums=0, static_argnames=("interpret",))
+def move_pages(pool, src, dst, interpret: bool = False):
+    """pool: (L, 2, P, page, kvH, hd), donated; src, dst: (M,) int32.
+    Returns the pool with page ``src[i]`` copied to page ``dst[i]`` in every
+    layer's K and V plane, in place (the output aliases the pool).
+
+    One grid step per move, one block of every plane's page through VMEM;
+    the pipeline fetches step i+1's source while it writes step i's
+    destination.  The moves must be as a stable pack gives them, both
+    ascending and ``dst[i] < src[i]``: a destination that is another
+    move's source then belongs to a later move, whose write starts after
+    that source was read.  A move repeated at once (padding) is fetched and
+    written once, as the pipeline skips an unchanged block index.
+    """
+    rest = (0,) * (pool.ndim - 3)
+    block = pool.shape[:2] + (None,) + pool.shape[3:]
+    return pl.pallas_call(
+        _move_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(src.shape[0],),
+            in_specs=[pl.BlockSpec(block,
+                                   lambda i, s, d: (0, 0, s[i]) + rest)],
+            out_specs=pl.BlockSpec(block,
+                                   lambda i, s, d: (0, 0, d[i]) + rest),
+        ),
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={2: 0},
+        interpret=interpret,
+    )(src.astype(jnp.int32), dst.astype(jnp.int32), pool)

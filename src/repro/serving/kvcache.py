@@ -7,9 +7,9 @@ Mapping of the paper's structures onto the serving tier:
   garbage               → pages of finished/evicted sequences
   hot/cold vSSTs        → ACTIVE vs FROZEN (paused/beam) sequence pools
   exposed-garbage ratio → free-list fragmentation of the pool
-  GC (lazy read + adaptive readahead)
-                        → run-coalesced live-page compaction
-                          (kernels/gc_compact; one DMA per live run)
+  GC (lazy read: only live data is read)
+                        → live-page compaction that moves only the pages
+                          whose index changes, every plane in one program
 
 Compaction keeps live pages dense at the front of the pool so admission
 of long prompts never fails on fragmentation; the scheduler triggers it
@@ -23,6 +23,7 @@ record ``serve.cache.*`` spans and ``cache.*`` counters through
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -30,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels import ops
+from ..kernels.gc_compact import move_pages
 from ..models.config import ModelConfig
 from ..obs import spans
 
@@ -38,7 +40,6 @@ from ..obs import spans
 class PagedCacheConfig:
     n_pages: int
     page_size: int = 16
-    compact_block_pages: int = 4
     use_pallas: bool = True
     interpret: Optional[bool] = None   # None: ops.interpret_mode decides
 
@@ -151,60 +152,57 @@ class PagedKVCache:
                 q, self.pool[layer, 0], self.pool[layer, 1], pt, ln,
                 use_pallas=self.pc.use_pallas, interpret=self.pc.interpret)
 
-    # -- GC: run-coalesced compaction (paper III-B.4 on HBM) ---------------
+    # -- GC: live-page compaction (paper III-B.4 on HBM) ------------------
     def compact(self) -> int:
-        """Pack live pages to the front of the pool.
+        """Pack live pages to the front of the pool, stably: the i-th live
+        page (in page order) goes to slot i and the free list becomes every
+        slot after the last live one.
 
-        Hot/cold placement (paper III-B.3): ACTIVE sequences' pages are
-        packed before FROZEN ones, so the hot region stays dense and the
-        next compaction touches mostly-cold long-lived pages.
+        Only the live pages whose index changes are read and written, in
+        every layer's K and V plane, by one program that updates the pool in
+        place (``kernels.gc_compact.move_pages``).  The pool is donated to
+        it: an array taken from ``self.pool`` before the call is deleted.
         Returns the pages relocated: live pages whose index changed, times
         the planes (layers x K/V) that hold them."""
         planes = self.cfg.n_layers * 2
-        bp = self.pc.compact_block_pages
         with spans.span("serve.cache.compact"):
             with spans.span("serve.cache.compact.plan"):
                 valid = np.zeros(self.pc.n_pages, bool)
-                for s, pages in self.tables.items():
-                    for p in pages:
-                        valid[p] = True
-                plan = ops.compact_plan(valid, bp) if self.pc.use_pallas \
-                    else None
-            # pool layout is (L, 2, P, ...): compact layer 0's K plane for
-            # the mapping, then move every plane with it.  ``copied`` counts
-            # the pages each gather's index asks for.
-            with spans.span("serve.cache.compact.kernel"):
-                plane = self.pool[0, 0].reshape(self.pc.n_pages,
-                                                self.pc.page_size, -1)
-                if plan is None:        # the reference gathers every page
-                    _, new_index, _ = ops.compact_pages(plane, valid)
-                    copied = plane.shape[0]
-                else:
-                    _, new_index, _ = ops.pack_pages(
-                        plane, plan, bp, interpret=self.pc.interpret)
-                    blocks, tail, _ = plan
-                    copied = len(blocks) * bp + len(tail)
-                new_index = np.asarray(new_index)
-            # apply the same permutation to the full pool in one gather
-            with spans.span("serve.cache.compact.permute"):
-                perm = np.arange(self.pc.n_pages)
-                for old, new in enumerate(new_index):
-                    if new >= 0:
-                        perm[new] = old
-                perm = jnp.asarray(perm)
-                self.pool = self.pool[:, :, perm]
-                copied += perm.size * self.pool.shape[0] * self.pool.shape[1]
-            # rewrite tables + free list
-            with spans.span("serve.cache.compact.tables"):
-                for s in self.tables:
-                    self.tables[s] = [int(new_index[p])
-                                      for p in self.tables[s]]
+                valid[np.fromiter(itertools.chain.from_iterable(
+                    self.tables.values()), np.intp)] = True
                 live = np.flatnonzero(valid)
                 n_live = len(live)
+                new_index = np.full(self.pc.n_pages, -1, np.int32)
+                new_index[live] = np.arange(n_live, dtype=np.int32)
+                src = live[new_index[live] != live].astype(np.int32)
+                dst = new_index[src]
+            # ``copied`` counts the pages the mover's index asks for
+            with spans.span("serve.cache.compact.move"):
+                copied = 0
+                if len(src):
+                    n = _bucket(len(src), self.pc.n_pages)
+                    # pad with the last move, which rewrites the same value;
+                    # an identity move on a page that another move writes
+                    # would race that write
+                    pad = n - len(src)
+                    self.pool = move_pages(
+                        self.pool, jnp.asarray(np.pad(src, (0, pad), "edge")),
+                        jnp.asarray(np.pad(dst, (0, pad), "edge")),
+                        interpret=ops.interpret_mode(self.pc.interpret))
+                    copied = n * planes
+            with spans.span("serve.cache.compact.tables"):
+                for s, pages in self.tables.items():
+                    self.tables[s] = new_index[pages].tolist()
                 self.free = list(range(self.pc.n_pages - 1, n_live - 1, -1))
-                relocated = int(np.count_nonzero(new_index[live] != live))
         self.compactions += 1
         spans.count("cache.compact.pages_live", n_live)
-        spans.count("cache.compact.pages_relocated", relocated * planes)
+        spans.count("cache.compact.pages_relocated", len(src) * planes)
         spans.count("cache.compact.pages_copied", copied)
-        return relocated * planes
+        return len(src) * planes
+
+
+def _bucket(moves: int, n_pages: int) -> int:
+    """The mover's index length for ``moves`` moves: the next power of two,
+    at least 16 and at most ``n_pages``, so one program serves a range of
+    compaction sizes."""
+    return min(max(16, 1 << (moves - 1).bit_length()), n_pages)

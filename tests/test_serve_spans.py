@@ -19,6 +19,18 @@ from repro.serving import (PagedCacheConfig, PagedKVCache, Request,
 
 MAX_BATCH = 4
 
+
+def _mover_index(moves, n_pages):
+    """The length of the mover's index for a compaction of ``moves`` pages:
+    the next power of two, at least 16 and at most the pool's pages."""
+    if not moves:
+        return 0
+    n = 16
+    while n < moves:
+        n *= 2
+    return min(n, n_pages)
+
+
 # span -> the span it opens under, in a ServeLoop run
 PARENTS = {
     "serve.sched.step": None,
@@ -28,8 +40,7 @@ PARENTS = {
     "serve.cache.page_table": "serve.cache.attend",
     "serve.cache.compact": "serve.sched.step",
     "serve.cache.compact.plan": "serve.cache.compact",
-    "serve.cache.compact.kernel": "serve.cache.compact",
-    "serve.cache.compact.permute": "serve.cache.compact",
+    "serve.cache.compact.move": "serve.cache.compact",
     "serve.cache.compact.tables": "serve.cache.compact",
 }
 
@@ -122,12 +133,13 @@ def test_counters_agree_with_the_page_tables(traced):
     c = traced["snap"]["counters"]
     loop, cache, tokens = traced["loop"], traced["cache"], traced["tokens"]
     planes = cache.cfg.n_layers * 2
-    relocated = live = 0
+    relocated = live = copied = 0
     for before, after, returned in cache.compacted:
         assert before.keys() == after.keys()
         moved = sum(a != b for s in before
                     for a, b in zip(before[s], after[s]))
         relocated += moved * planes
+        copied += _mover_index(moved, cache.pc.n_pages) * planes
         assert returned == moved * planes
         live += sum(len(t) for t in before.values())
         # live pages end dense at the front of the pool
@@ -136,8 +148,7 @@ def test_counters_agree_with_the_page_tables(traced):
     assert relocated > 0
     assert c["cache.compact.pages_relocated"] == relocated
     assert c["cache.compact.pages_live"] == live
-    assert c["cache.compact.pages_copied"] == (
-        len(cache.compacted) * cache.pc.n_pages * planes + live)
+    assert c["cache.compact.pages_copied"] == copied
     assert c["cache.kv_rows_written"] == 2 * tokens
     assert c["sched.decode_seqs"] == tokens
     assert c["sched.decode_iterations"] == loop.decode_steps
@@ -205,9 +216,8 @@ def test_train_step_names_its_scopes():
 
 @pytest.mark.parametrize("use_pallas", [True, False])
 def test_pages_copied_counts_what_the_gathers_ask_for(use_pallas, tmp_path):
-    """Layer 0's K plane through the kernel (the live pages of the plan's
-    blocks and tail) or the reference (every page), then every plane
-    through the permute."""
+    """The mover's index, every plane: the 4 pages that move, padded to the
+    smallest bucket (16), whichever attention path the cache serves."""
     cfg = get_config("olmo-1b", smoke=True)
     cache = PagedKVCache(cfg, PagedCacheConfig(
         n_pages=16, page_size=4, use_pallas=use_pallas, interpret=True))
@@ -219,7 +229,6 @@ def test_pages_copied_counts_what_the_gathers_ask_for(use_pallas, tmp_path):
         cache.compact()
     c = spans.snapshot()["counters"]
     planes = cfg.n_layers * 2
-    kernel = live if use_pallas else cache.pc.n_pages
-    assert c["cache.compact.pages_copied"] == (
-        kernel + cache.pc.n_pages * planes)
+    assert c["cache.compact.pages_relocated"] == 4 * planes
+    assert c["cache.compact.pages_copied"] == 16 * planes
     assert c["cache.compact.pages_live"] == live

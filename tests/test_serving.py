@@ -1,10 +1,15 @@
 """Serving: paged cache correctness (attend == dense reference), GC
-compaction preserves live data, scheduler completes all requests."""
+compaction preserves live data in every plane as a stable pack, one mover
+program per bucket of compaction sizes, scheduler completes all
+requests."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_config
+from repro.kernels.gc_compact import move_pages
 from repro.serving import (PagedCacheConfig, PagedKVCache, Request,
                            ServeConfig, ServeLoop)
 
@@ -67,6 +72,68 @@ def test_compaction_preserves_live_kv():
     np.testing.assert_allclose(np.asarray(after), np.asarray(before),
                                atol=1e-6)
     assert cache.fragmentation() <= frag_before
+
+
+def _holed(page_counts, finished, use_pallas=True):
+    """A 64-page cache whose pool is filled from a seed, holding one
+    sequence of each page count (allocated in order), less the finished."""
+    cfg = get_config("olmo-1b", smoke=True)
+    cache = PagedKVCache(cfg, PagedCacheConfig(
+        n_pages=64, page_size=2, use_pallas=use_pallas, interpret=True))
+    cache.pool = jax.random.normal(jax.random.PRNGKey(5), cache.pool.shape,
+                                   jnp.float32).astype(cache.pool.dtype)
+    for s, n in enumerate(page_counts):
+        assert cache.add_sequence(s, n * cache.pc.page_size)
+    for s in finished:
+        cache.finish_sequence(s)
+    return cfg, cache
+
+
+# case -> (pages of each sequence, sequences finished, pages that move)
+GC_CASES = {
+    # 2->0, 3->1, 4->2: the first move's source is the third's destination
+    "3_moves": ((2, 3), (0,), 3),
+    # two holes: 1,2 -> 0,1 and 4,5,6 -> 2,3,4
+    "5_moves": ((1, 2, 1, 3), (0, 2), 5),
+    # past the smallest bucket (16)
+    "17_moves": ((4, 17), (0,), 17),
+    # the hole is at the end: nothing moves
+    "0_moves": ((3, 2), (1,), 0),
+}
+
+
+@pytest.mark.parametrize("use_pallas", [True, False])
+@pytest.mark.parametrize("case", sorted(GC_CASES))
+def test_compaction_is_a_stable_pack_in_every_plane(case, use_pallas):
+    """Every layer's K and V plane, the tables and the free list after
+    ``compact()`` against a NumPy stable pack of the pool before it."""
+    pages, finished, moves = GC_CASES[case]
+    cfg, cache = _holed(pages, finished, use_pallas)
+    before = np.asarray(cache.pool).view(np.uint16)
+    tables = {s: list(t) for s, t in cache.tables.items()}
+    relocated = cache.compact()
+
+    live = sorted(p for t in tables.values() for p in t)
+    rank = {p: i for i, p in enumerate(live)}
+    assert sum(rank[p] != p for p in live) == moves
+    assert relocated == moves * cfg.n_layers * 2
+    assert cache.tables == {s: [rank[p] for p in t]
+                            for s, t in tables.items()}
+    assert cache.free == list(range(cache.pc.n_pages - 1, len(live) - 1, -1))
+    after = np.asarray(cache.pool).view(np.uint16)
+    np.testing.assert_array_equal(after[:, :, :len(live)],
+                                  before[:, :, live])
+
+
+def test_compactions_in_one_bucket_share_one_mover_program():
+    move_pages.clear_cache()
+    for moves in (3, 5, 9, 16):         # all padded to 16 moves
+        _, cache = _holed((2, moves), (0,))
+        cache.compact()
+    assert move_pages._cache_size() == 1
+    _, cache = _holed((2, 17), (0,))     # 32
+    cache.compact()
+    assert move_pages._cache_size() == 2
 
 
 def test_scheduler_completes_all_requests_with_compaction():

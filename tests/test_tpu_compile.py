@@ -14,7 +14,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.gc_compact import gather_page_blocks
+from repro.kernels.gc_compact import gather_page_blocks, move_pages
 from repro.kernels.paged_attention import paged_attention
 
 
@@ -62,6 +62,20 @@ def test_gather_page_blocks_compiles_for_v5e(one_chip, block_pages):
     _compile(gather_page_blocks, one_chip,
              ((2048, 16, 2048), jnp.bfloat16), ((64,), jnp.int32),
              block_pages=block_pages)
+
+
+@pytest.mark.parametrize("moves", [16, 256])
+def test_move_pages_compiles_for_v5e_in_place(one_chip, moves):
+    # the whole olmo-1b pool (16 layers x K/V, 1024 pages of 16 tokens,
+    # 16 heads x 128): the output is the donated pool, with no temporary
+    pool = ((16, 2, 1024, 16, 16, 128), jnp.bfloat16)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in (pool, ((moves,), jnp.int32), ((moves,), jnp.int32))]
+    compiled = move_pages.lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 ** 31
+    assert mem.temp_size_in_bytes == 0
 
 
 def test_flash_attention_compiles_for_v5e(one_chip):
