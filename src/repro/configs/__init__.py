@@ -13,6 +13,7 @@ ARCHS = [
     "grok_1_314b", "granite_moe_3b_a800m", "phi3_medium_14b",
     "phi3_mini_3_8b", "starcoder2_3b", "olmo_1b", "hubert_xlarge",
     "mamba2_370m", "jamba_v0_1_52b", "qwen2_vl_2b",
+    "nemotron3_nano_30b_a3b",
 ]
 
 ALIASES = {a.replace("_", "-"): a for a in ARCHS}

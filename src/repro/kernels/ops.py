@@ -46,6 +46,21 @@ def decode_attention(q, k_pool, v_pool, page_table, lengths,
     return ref.paged_attention_ref(q, k_pool, v_pool, page_table, lengths)
 
 
+def gmm(lhs, rhs, group_sizes, interpret: Optional[bool] = None):
+    """Grouped matmul (megablox, forward and backward): the rows of group
+    i of ``lhs`` (M, K) times ``rhs[i]`` (K, N) for the ``len(rhs)`` first
+    groups of ``group_sizes``; the rows of any further group come out zero.
+    Tiles of 512 rows (M a multiple of them), 1024 of K and of N: on one
+    v5e they took 8.7 ms for an expert MLP's forward and backward (8 groups
+    of 2688 x 1856, 6131 of 98304 rows) where 128-tiles took 39.6 ms and
+    ``jax.lax.ragged_dot`` 20.9 ms."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+    m, k = lhs.shape
+    tiling = (min(512, m), min(1024, k), min(1024, rhs.shape[-1]))
+    return megablox.gmm(lhs, rhs, group_sizes, lhs.dtype, tiling, None, None,
+                        False, interpret_mode(interpret))
+
+
 def ssd(x, dt, a, bmat, cmat, chunk: int = 128, use_pallas: bool = False,
         interpret: Optional[bool] = None):
     if use_pallas:

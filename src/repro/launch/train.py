@@ -34,7 +34,7 @@ from ..configs import get_config
 from ..models import get_model
 from ..train.data import synthetic_batch
 from ..train.optimizer import AdamWConfig, init_state
-from ..train.step import TrainConfig, build_train_step
+from ..train.step import TrainConfig, build_train_step, record_routing
 from .compile_cache import use_compile_cache
 from .mesh import make_host_mesh
 
@@ -99,6 +99,7 @@ def main(argv=None) -> int:
         params, opt, metrics = jit_step(params, opt, batch)
         loss = float(metrics["loss"])
         dt = time.perf_counter() - t0
+        record_routing(metrics)
         ewma = dt if ewma is None else 0.8 * ewma + 0.2 * dt
         straggler = dt > args.straggler_factor * ewma and step > start_step
         print(f"step={step} loss={loss:.4f} "

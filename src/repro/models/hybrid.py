@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from .config import ModelConfig
 from .modules import (ParamSpec, attention_specs, axes_tree, dense_ffn,
-                      ffn_specs, gqa_attention, materialize, moe_ffn, norm)
+                      ffn, ffn_specs, gqa_attention, materialize, norm)
 from .ssm import (D_CONV, ssd_decode_step, ssd_layer, ssd_layer_specs)
 
 Params = Dict[str, Any]
@@ -89,7 +89,7 @@ def _apply_position(cfg: ModelConfig, role, lp: Params, x, positions):
         x = ssd_layer(lp["mamba"], x, cfg)
     xn = norm(x, lp["ffn_norm"], cfg)
     if ffn_kind == "moe":
-        x = x + moe_ffn(lp["ffn"], xn, cfg)
+        x = x + ffn(lp["ffn"], xn, cfg)
     else:
         x = x + dense_ffn(lp["ffn"], xn, _dense_cfg(cfg))
     return x
@@ -133,7 +133,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
                abstract: bool = False):
     n_blocks = cfg.n_layers // cfg.attn_every
     n_mamba = cfg.attn_every - 1
-    conv_dim = cfg.d_inner + 2 * cfg.ssm_state
+    conv_dim = cfg.d_inner + 2 * cfg.ssm_groups * cfg.ssm_state
     shapes = {
         "kv": (n_blocks, 2, batch, max_seq, cfg.kv_heads, cfg.head_dim),
         "conv": (n_blocks, n_mamba, batch, D_CONV - 1, conv_dim),
@@ -189,7 +189,7 @@ def decode_step(params: Params, cache, lengths, tokens, cfg: ModelConfig):
                 m += 1
             xn = norm(x, lp["ffn_norm"], cfg)
             if ffn_kind == "moe":
-                x = x + moe_ffn(lp["ffn"], xn, cfg)
+                x = x + ffn(lp["ffn"], xn, cfg)
             else:
                 x = x + dense_ffn(lp["ffn"], xn, _dense_cfg(cfg))
         return x, (new_kv, jnp.stack(new_conv), jnp.stack(new_ssm))

@@ -5,10 +5,11 @@ Parameters are nested dicts of arrays.  ``abstract=True`` builds
 dry-run lowers against these).  Every parameter carries *logical axis*
 names in a parallel tree, consumed by ``repro.parallel.sharding``.
 
-Attention/FFN math uses plain jnp (XLA-fusable and SPMD-partitionable);
-the Pallas TPU kernels in ``repro.kernels`` implement the same contracts
-for the perf-critical paths and are validated against these references in
-interpret mode (CPU container — see DESIGN.md §6).
+Attention/FFN math uses plain jnp (XLA-fusable and SPMD-partitionable),
+except where a Pallas kernel of ``repro.kernels`` takes its place: causal
+self-attention on TPUs (splash) and the experts (``models.moe``), each run
+per device shard under a mesh.  The kernels are checked against these
+references in interpret mode on the CPU.
 """
 
 from __future__ import annotations
@@ -19,6 +20,9 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
+
+from ..kernels.train_attention import block_for, causal_attention
 
 Params = Dict[str, Any]
 
@@ -90,7 +94,7 @@ def layernorm_nonparametric(x, eps: float = 1e-5):
 def norm(x, gamma, cfg) -> jax.Array:
     if cfg.ln_kind == "nonparametric":
         return layernorm_nonparametric(x)
-    return rmsnorm(x, gamma)
+    return rmsnorm(x, gamma, cfg.norm_eps)
 
 
 # --------------------------------------------------------------------------
@@ -164,6 +168,30 @@ def _rope_qk(q, k, positions, cfg):
     return q, k
 
 
+def _kernel_fits(s: int) -> bool:
+    """Causal self-attention runs through the splash kernel (forward and
+    backward, no S x S tensor) on TPUs where its blocks tile the sequence;
+    elsewhere over the S x S scores."""
+    from ..parallel.ctx import platform
+    return platform() == "tpu" and block_for(s) > 0
+
+
+def _kernel_attention(q, k, v):
+    """``causal_attention`` on each device's shard of the enclosing mesh:
+    its batch rows, and its heads where query and KV heads shard alike."""
+    from ..parallel import ctx
+    if ctx.sharded_mesh() is None:
+        return causal_attention(q, k, v)
+    qs = ctx.spec_of(q, ("act_batch", None, "heads", None))
+    ks = ctx.spec_of(k, ("act_batch", None, "kv_heads", None))
+    b_ax, h_ax = ctx.entry(qs, 0), ctx.entry(qs, 2)
+    if h_ax != ctx.entry(ks, 2):
+        h_ax = None
+    qs, ks = P(b_ax, None, h_ax), P(ctx.entry(ks, 0), None, h_ax)
+    return ctx.shard_local(causal_attention, in_specs=(qs, ks, ks),
+                           out_specs=qs)(q, k, v)
+
+
 def gqa_attention(p: Params, x, positions, cfg, causal: bool = True,
                   kv_override: Optional[Tuple[jax.Array, jax.Array]] = None,
                   kv_positions: Optional[jax.Array] = None):
@@ -188,8 +216,9 @@ def gqa_attention(p: Params, x, positions, cfg, causal: bool = True,
         kv_pos = kv_positions
     groups = cfg.n_heads // cfg.kv_heads
     qg = q.reshape(b, s, cfg.kv_heads, groups, cfg.head_dim)
-    if cfg.attn_impl == "chunked" and kv_override is None and causal:
-        ctx = _chunked_causal_attention(qg, k, v, cfg)
+    if kv_override is None and causal and _kernel_fits(s):
+        with jax.named_scope("attn"):
+            ctx = _kernel_attention(q, k, v)
     else:
         scores = jnp.einsum("bskgd,btkd->bkgst", qg, k) \
             / math.sqrt(cfg.head_dim)
@@ -208,64 +237,14 @@ def gqa_attention(p: Params, x, positions, cfg, causal: bool = True,
     return out, (k, v)
 
 
-def _chunked_causal_attention(qg, k, v, cfg):
-    """Streaming-softmax attention over KV chunks (flash contract in jnp):
-    never materializes the (S, S) score matrix — the memory-roofline
-    optimization for long prefill (§Perf cell B).  On TPU hardware the
-    Pallas flash kernel implements the identical math."""
-    b, s, kvh, g, d = qg.shape
-    ck = min(cfg.attn_chunk, s)
-    n_chunks = s // ck
-    scale = 1.0 / math.sqrt(d)
-    kc = k.reshape(b, n_chunks, ck, kvh, d)
-    vc = v.reshape(b, n_chunks, ck, kvh, d)
-    q_pos = jnp.arange(s)
-
-    def body(carry, inp):
-        m, l, acc = carry
-        kj, vj, j = inp
-        sc = jnp.einsum("bskgd,btkd->bkgst", qg, kj) * scale
-        kv_pos = j * ck + jnp.arange(ck)
-        mask = q_pos[:, None] >= kv_pos[None, :]
-        sc = jnp.where(mask[None, None, None], sc.astype(jnp.float32),
-                       -1e30)
-        m_new = jnp.maximum(m, sc.max(axis=-1))
-        p = jnp.exp(sc - m_new[..., None])
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + p.sum(axis=-1)
-        acc_new = acc * alpha[..., None] + jnp.einsum(
-            "bkgst,btkd->bkgsd", p.astype(cfg.compute_dtype),
-            vj).astype(jnp.float32)
-        return (m_new, l_new, acc_new), None
-
-    m0 = jnp.full((b, kvh, g, s), -1e30, jnp.float32)
-    l0 = jnp.zeros((b, kvh, g, s), jnp.float32)
-    a0 = jnp.zeros((b, kvh, g, s, d), jnp.float32)
-    (m, l, acc), _ = jax.lax.scan(
-        body, (m0, l0, a0),
-        (jnp.moveaxis(kc, 1, 0), jnp.moveaxis(vc, 1, 0),
-         jnp.arange(n_chunks)))
-    ctx = (acc / jnp.maximum(l, 1e-30)[..., None]) \
-        .astype(cfg.compute_dtype)
-    return jnp.moveaxis(ctx, 3, 1).reshape(b, s, kvh, g, d)
-
-
 # --------------------------------------------------------------------------
-# FFN: dense (SwiGLU / GELU) and Mixture-of-Experts
+# FFN: dense (SwiGLU / GELU); experts in ``models.moe``
 # --------------------------------------------------------------------------
 
 def ffn_specs(cfg) -> Params:
     if cfg.n_experts > 1:
-        e = cfg.n_experts
-        return {
-            "router": ParamSpec((cfg.d_model, e), ("embed", "expert")),
-            "wi": ParamSpec((e, cfg.d_model, cfg.d_ff),
-                            ("expert", "embed", "mlp")),
-            "wg": ParamSpec((e, cfg.d_model, cfg.d_ff),
-                            ("expert", "embed", "mlp")),
-            "wo": ParamSpec((e, cfg.d_ff, cfg.d_model),
-                            ("expert", "mlp", "embed")),
-        }
+        from . import moe
+        return moe.specs(cfg)
     if cfg.ffn_act == "swiglu":
         return {
             "wi": ParamSpec((cfg.d_model, cfg.d_ff), ("embed", "mlp")),
@@ -287,58 +266,8 @@ def dense_ffn(p: Params, x, cfg):
     return h @ p["wo"]
 
 
-def moe_ffn(p: Params, x, cfg):
-    """Top-k MoE with capacity-based sort dispatch (grouped GEMM).
-
-    Tokens are flattened, routed, sorted by expert, packed into an
-    (E, C, D) buffer (overflow dropped — capacity factor 1.25), processed
-    with per-expert einsums (EP-shardable on the 'expert' axis; the
-    pack/unpack scatter induces the expected all-to-all), and combined
-    with router weights.
-    """
-    b, s, d = x.shape
-    p = jax.tree.map(lambda a: a.astype(cfg.compute_dtype), p)
-    n = b * s
-    xt = x.reshape(n, d).astype(cfg.compute_dtype)
-    e, k = cfg.n_experts, cfg.top_k
-    logits = (xt @ p["router"]).astype(jnp.float32)          # (N, E)
-    gates, idx = jax.lax.top_k(logits, k)                    # (N, k)
-    gates = jax.nn.softmax(gates, axis=-1).astype(cfg.compute_dtype)
-    cap = int(math.ceil(n * k / e * cfg.capacity_factor))
-    cap = max(cap, 8)
-
-    flat_e = idx.reshape(-1)                                 # (N*k,)
-    order = jnp.argsort(flat_e)                              # stable
-    sorted_e = flat_e[order]
-    # rank of each pair within its expert
-    starts = jnp.searchsorted(sorted_e, jnp.arange(e))
-    rank = jnp.arange(n * k) - starts[sorted_e]
-    keep = rank < cap
-    slot = jnp.where(keep, sorted_e * cap + rank, e * cap)   # overflow bin
-    tok = order // k                                         # source token
-
-    from ..parallel.ctx import constrain
-    buf = jnp.zeros((e * cap + 1, d), cfg.compute_dtype)
-    buf = buf.at[slot].add(xt[tok].astype(cfg.compute_dtype))
-    # expert-sharded buffer: the scatter above lowers to the expected
-    # token all-to-all under expert parallelism
-    buf = constrain(buf[:-1].reshape(e, cap, d), ("expert", None, None))
-
-    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buf, p["wg"])) * \
-        jnp.einsum("ecd,edf->ecf", buf, p["wi"])
-    out_e = jnp.einsum("ecf,efd->ecd", h, p["wo"])           # (E, C, D)
-
-    flat_out = jnp.concatenate(
-        [out_e.reshape(e * cap, d),
-         jnp.zeros((1, d), out_e.dtype)], axis=0)
-    pair_out = flat_out[slot]                                # (N*k, D)
-    pair_gate = gates.reshape(-1)[order]
-    y = jnp.zeros((n, d), cfg.compute_dtype)
-    y = y.at[tok].add(pair_out * pair_gate[:, None])
-    return y.reshape(b, s, d).astype(x.dtype)
-
-
 def ffn(p: Params, x, cfg):
     if cfg.n_experts > 1:
-        return moe_ffn(p, x, cfg)
+        from . import moe
+        return moe.moe(p, x, cfg)[0]
     return dense_ffn(p, x, cfg)

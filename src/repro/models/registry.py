@@ -4,7 +4,7 @@ from __future__ import annotations
 
 from types import ModuleType
 
-from . import hybrid, ssm, transformer
+from . import hybrid, nemotron_h, ssm, transformer
 from .config import ModelConfig
 
 
@@ -13,6 +13,8 @@ def get_model(cfg: ModelConfig) -> ModuleType:
         return ssm
     if cfg.family == "hybrid":
         return hybrid
+    if cfg.family == "nemotron_h":
+        return nemotron_h
     return transformer  # dense | moe | audio | vlm
 
 

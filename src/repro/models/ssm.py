@@ -26,11 +26,11 @@ D_CONV = 4
 
 
 def ssd_layer_specs(cfg: ModelConfig) -> Params:
-    d, di, st, h = cfg.d_model, cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
-    conv_dim = di + 2 * st
-    return {
+    d, di, h = cfg.d_model, cfg.d_inner, cfg.ssm_heads
+    conv_dim = di + 2 * cfg.ssm_groups * cfg.ssm_state
+    specs = {
         "norm": ParamSpec((d,), ("embed",)),
-        "w_in": ParamSpec((d, 2 * di + 2 * st + h), ("embed", "inner_all")),
+        "w_in": ParamSpec((d, di + conv_dim + h), ("embed", "inner_all")),
         "conv_w": ParamSpec((D_CONV, conv_dim), ("conv_k", "inner_conv")),
         "a_log": ParamSpec((h,), ("ssm_heads",)),
         "d_skip": ParamSpec((h,), ("ssm_heads",)),
@@ -38,22 +38,50 @@ def ssd_layer_specs(cfg: ModelConfig) -> Params:
         "out_norm": ParamSpec((di,), ("inner",)),
         "w_out": ParamSpec((di, d), ("inner", "embed")),
     }
+    if cfg.conv_bias:
+        specs["conv_b"] = ParamSpec((conv_dim,), ("inner_conv",))
+    return specs
 
 
 def _split_proj(cfg: ModelConfig, proj):
-    di, st, h = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
-    z = proj[..., :di]
-    xbc = proj[..., di:di + di + 2 * st]
-    dt = proj[..., di + di + 2 * st:]
-    return z, xbc, dt
+    """(z, xBC, dt) of the input projection."""
+    di = cfg.d_inner
+    conv_dim = di + 2 * cfg.ssm_groups * cfg.ssm_state
+    return (proj[..., :di], proj[..., di:di + conv_dim],
+            proj[..., di + conv_dim:])
 
 
-def _causal_conv(xbc, conv_w):
-    """Depthwise causal conv along seq: xbc (B,S,C), conv_w (K,C)."""
+def _split_xbc(cfg: ModelConfig, xbc):
+    """x (..., H, P), B and C (..., G, N) of the conv's output."""
+    di, g, n = cfg.d_inner, cfg.ssm_groups, cfg.ssm_state
+    lead = xbc.shape[:-1]
+    return (xbc[..., :di].reshape(lead + (cfg.ssm_heads, cfg.ssm_headdim)),
+            xbc[..., di:di + g * n].reshape(lead + (g, n)),
+            xbc[..., di + g * n:].reshape(lead + (g, n)))
+
+
+def _gated_norm(y, z, gain, cfg: ModelConfig):
+    """RMSNorm of y·silu(z) over each group's d_inner / G channels, in
+    float32, times the gain."""
+    v = y * jax.nn.silu(z)
+    lead, g = v.shape[:-1], cfg.ssm_groups
+    if g == 1:
+        return rmsnorm(v, gain, cfg.norm_eps)
+    v32 = v.astype(jnp.float32).reshape(lead + (g, v.shape[-1] // g))
+    v32 = v32 * jax.lax.rsqrt(jnp.mean(v32 * v32, axis=-1, keepdims=True)
+                              + cfg.norm_eps)
+    return (v32.reshape(v.shape) * gain).astype(v.dtype)
+
+
+def _causal_conv(xbc, conv_w, conv_b=None):
+    """Depthwise causal conv along seq: xbc (B,S,C), conv_w (K,C), an
+    optional bias (C,); SiLU after."""
     k = conv_w.shape[0]
     pad = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
     out = sum(pad[:, i:i + xbc.shape[1], :] * conv_w[i][None, None, :]
               for i in range(k))
+    if conv_b is not None:
+        out = out + conv_b
     return jax.nn.silu(out)
 
 
@@ -72,51 +100,59 @@ def _segsum_exp(dA_cs):
 def ssd_chunked(x, dt, a, bmat, cmat, chunk: int,
                 initial_state: Optional[jax.Array] = None
                 ) -> Tuple[jax.Array, jax.Array]:
-    """SSD scan. x:(B,S,H,P) dt:(B,S,H) a:(H,)<0 bmat/cmat:(B,S,N).
-    Returns (y:(B,S,H,P), final_state:(B,H,P,N))."""
+    """SSD scan. x:(B,S,H,P) dt:(B,S,H) a:(H,)<0 bmat/cmat:(B,S,G,N), head
+    h reading group h // (H/G).
+    Returns (y:(B,S,H,P), final_state:(B,H,P,N)).
+
+    Heads are laid out (G, R) with R = H/G heads a group; with one group
+    the group axis is dropped, so that B and C broadcast over every head
+    as in the ungrouped scan."""
     b, s, h, p = x.shape
-    n = bmat.shape[-1]
-    assert s % chunk == 0, (s, chunk)
+    g, n = bmat.shape[-2:]
+    assert s % chunk == 0 and h % g == 0, (s, chunk, h, g)
     nc = s // chunk
-    xc = x.reshape(b, nc, chunk, h, p)
-    dtc = dt.reshape(b, nc, chunk, h)
-    bc = bmat.reshape(b, nc, chunk, n)
-    cc = cmat.reshape(b, nc, chunk, n)
+    G = "g" if g > 1 else ""                     # the group axis, if any
+    heads = (g, h // g) if g > 1 else (h,)       # ([G,] R)
+    xc = x.reshape(b, nc, chunk, *heads, p)
+    dtc = dt.reshape(b, nc, chunk, *heads)
+    bc = bmat.reshape(b, nc, chunk, *heads[:-1], n)
+    cc = cmat.reshape(b, nc, chunk, *heads[:-1], n)
 
-    dA = dtc * a                                   # (B,nc,cl,H)
-    dA_cs = jnp.cumsum(dA, axis=2)                 # (B,nc,cl,H)
-    decay = _segsum_exp(jnp.moveaxis(dA_cs, -1, -2))   # (B,nc,H,cl,cl)
+    dA = dtc * a.reshape(heads)                    # (B,nc,cl,[G,]R)
+    dA_cs = jnp.cumsum(dA, axis=2)                 # (B,nc,cl,[G,]R)
+    decay = _segsum_exp(jnp.moveaxis(dA_cs, 2, -1))    # (B,nc,[G,]R,cl,cl)
 
-    xdt = xc * dtc[..., None]                      # (B,nc,cl,H,P)
-    scores = jnp.einsum("bcin,bcjn->bcij", cc, bc)     # (B,nc,cl,cl)
-    gated = decay * scores[:, :, None, :, :]           # (B,nc,H,cl,cl)
-    y_diag = jnp.einsum("bchij,bcjhp->bcihp", gated, xdt)
+    xdt = xc * dtc[..., None]                      # (B,nc,cl,[G,]R,P)
+    scores = jnp.einsum(f"bci{G}n,bcj{G}n->bc{G}ij", cc, bc)
+    gated = decay * scores[..., None, :, :]        # (B,nc,[G,]R,cl,cl)
+    y_diag = jnp.einsum(f"bc{G}rij,bcj{G}rp->bci{G}rp", gated, xdt)
 
     # chunk-final states: sum_j exp(dA_sum - dA_cs_j) dt_j B_j x_j
-    dA_sum = dA_cs[:, :, -1:, :]                   # (B,nc,1,H)
-    state_decay = jnp.exp(dA_sum - dA_cs)          # (B,nc,cl,H)
-    chunk_states = jnp.einsum("bcjn,bcjh,bcjhp->bchpn",
+    dA_sum = dA_cs[:, :, -1:]                      # (B,nc,1,[G,]R)
+    state_decay = jnp.exp(dA_sum - dA_cs)          # (B,nc,cl,[G,]R)
+    chunk_states = jnp.einsum(f"bcj{G}n,bcj{G}r,bcj{G}rp->bc{G}rpn",
                               bc, state_decay * dtc, xc)
 
     # inter-chunk recurrence
-    chunk_decay = jnp.exp(dA_sum[:, :, 0, :])      # (B,nc,H)
-    init = (jnp.zeros((b, h, p, n), x.dtype)
-            if initial_state is None else initial_state)
+    chunk_decay = jnp.exp(dA_sum[:, :, 0])         # (B,nc,[G,]R)
+    init = (jnp.zeros((b, *heads, p, n), x.dtype) if initial_state is None
+            else initial_state.reshape(b, *heads, p, n))
 
     def scan_fn(carry, inp):
-        cs, cd = inp                               # (B,H,P,N), (B,H)
+        cs, cd = inp                               # (B,[G,]R,P,N), (B,[G,]R)
         new = carry * cd[..., None, None] + cs
         return new, carry                          # emit state *entering*
 
     final, prev_states = jax.lax.scan(
         scan_fn, init,
         (jnp.moveaxis(chunk_states, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)))
-    prev_states = jnp.moveaxis(prev_states, 0, 1)  # (B,nc,H,P,N)
+    prev_states = jnp.moveaxis(prev_states, 0, 1)  # (B,nc,[G,]R,P,N)
 
-    in_decay = jnp.exp(dA_cs)                      # (B,nc,cl,H)
-    y_off = jnp.einsum("bcin,bchpn,bcih->bcihp", cc, prev_states, in_decay)
+    in_decay = jnp.exp(dA_cs)                      # (B,nc,cl,[G,]R)
+    y_off = jnp.einsum(f"bci{G}n,bc{G}rpn,bci{G}r->bci{G}rp", cc,
+                       prev_states, in_decay)
     y = (y_diag + y_off).reshape(b, s, h, p)
-    return y, final
+    return y, final.reshape(b, h, p, n)
 
 
 def ssd_layer(lp: Params, x, cfg: ModelConfig,
@@ -128,24 +164,23 @@ def ssd_layer(lp: Params, x, cfg: ModelConfig,
     xn = norm(x, lp["norm"], cfg)
     proj = (xn @ lp["w_in"].astype(cfg.compute_dtype))
     z, xbc, dt = _split_proj(cfg, proj)
-    xbc = _causal_conv(xbc, lp["conv_w"].astype(cfg.compute_dtype))
-    di, st = cfg.d_inner, cfg.ssm_state
-    xs = xbc[..., :di]
-    bmat = xbc[..., di:di + st]
-    cmat = xbc[..., di + st:]
-    h, p = cfg.ssm_heads, cfg.ssm_headdim
-    xh = xs.reshape(xs.shape[0], xs.shape[1], h, p)
+    conv_b = lp.get("conv_b")
+    xbc = _causal_conv(xbc, lp["conv_w"].astype(cfg.compute_dtype),
+                       None if conv_b is None
+                       else conv_b.astype(cfg.compute_dtype))
+    xh, bmat, cmat = _split_xbc(cfg, xbc)
     dt_soft = jax.nn.softplus(dt.astype(jnp.float32)
                               + lp["dt_bias"].astype(jnp.float32))
     a = -jnp.exp(lp["a_log"].astype(jnp.float32))
-    y, state = ssd_chunked(xh.astype(jnp.float32), dt_soft, a,
-                           bmat.astype(jnp.float32),
-                           cmat.astype(jnp.float32), cfg.ssm_chunk,
-                           initial_state)
+    with jax.named_scope("ssd"):
+        y, state = ssd_chunked(xh.astype(jnp.float32), dt_soft, a,
+                               bmat.astype(jnp.float32),
+                               cmat.astype(jnp.float32), cfg.ssm_chunk,
+                               initial_state)
     y = y + lp["d_skip"].astype(jnp.float32)[None, None, :, None] \
         * xh.astype(jnp.float32)
-    y = y.reshape(xs.shape).astype(cfg.compute_dtype)
-    y = rmsnorm(y * jax.nn.silu(z), lp["out_norm"])
+    y = y.reshape(z.shape).astype(cfg.compute_dtype)
+    y = _gated_norm(y, z, lp["out_norm"], cfg)
     out = y @ lp["w_out"].astype(cfg.compute_dtype)
     if return_state:
         return x + out, state
@@ -160,26 +195,26 @@ def ssd_decode_step(lp: Params, x1, conv_state, ssm_state, cfg: ModelConfig):
     z, xbc, dt = _split_proj(cfg, proj)
     window = jnp.concatenate([conv_state, xbc], axis=1)      # (B,K,C)
     conv_w = lp["conv_w"].astype(cfg.compute_dtype)
-    conv_out = jax.nn.silu(jnp.einsum("bkc,kc->bc", window, conv_w))[:, None]
+    conv = jnp.einsum("bkc,kc->bc", window, conv_w)
+    if "conv_b" in lp:
+        conv = conv + lp["conv_b"].astype(cfg.compute_dtype)
+    conv_out = jax.nn.silu(conv)
     new_conv_state = window[:, 1:]
-    di, st = cfg.d_inner, cfg.ssm_state
-    xs = conv_out[..., :di]
-    bmat = conv_out[..., di:di + st]
-    cmat = conv_out[..., di + st:]
-    h, p = cfg.ssm_heads, cfg.ssm_headdim
-    xh = xs.reshape(-1, h, p).astype(jnp.float32)            # (B,H,P)
+    xh, bv, cv = _split_xbc(cfg, conv_out)                   # (B,H,P), (B,G,N)
+    xh = xh.astype(jnp.float32)
+    r = cfg.ssm_heads // cfg.ssm_groups
+    bv = jnp.repeat(bv.astype(jnp.float32), r, axis=1)       # (B,H,N)
+    cv = jnp.repeat(cv.astype(jnp.float32), r, axis=1)
     dt_soft = jax.nn.softplus(
         dt[:, 0].astype(jnp.float32) + lp["dt_bias"].astype(jnp.float32))
     a = -jnp.exp(lp["a_log"].astype(jnp.float32))
     decay = jnp.exp(dt_soft * a)                             # (B,H)
-    bv = bmat[:, 0].astype(jnp.float32)                      # (B,N)
-    cv = cmat[:, 0].astype(jnp.float32)
     new_state = ssm_state * decay[..., None, None] + \
-        (dt_soft[..., None] * xh)[..., None] * bv[:, None, None, :]
-    y = jnp.einsum("bhpn,bn->bhp", new_state, cv)
+        (dt_soft[..., None] * xh)[..., None] * bv[:, :, None, :]
+    y = jnp.einsum("bhpn,bhn->bhp", new_state, cv)
     y = y + lp["d_skip"].astype(jnp.float32)[None, :, None] * xh
-    y = y.reshape(x1.shape[0], 1, di).astype(cfg.compute_dtype)
-    y = rmsnorm(y * jax.nn.silu(z), lp["out_norm"])
+    y = y.reshape(x1.shape[0], 1, cfg.d_inner).astype(cfg.compute_dtype)
+    y = _gated_norm(y, z, lp["out_norm"], cfg)
     out = y @ lp["w_out"].astype(cfg.compute_dtype)
     return x1 + out, new_conv_state, new_state
 
@@ -237,7 +272,7 @@ def loss_fn(params: Params, batch: Dict, cfg: ModelConfig) -> jax.Array:
 
 
 def init_cache(cfg: ModelConfig, batch: int, abstract: bool = False):
-    conv_dim = cfg.d_inner + 2 * cfg.ssm_state
+    conv_dim = cfg.d_inner + 2 * cfg.ssm_groups * cfg.ssm_state
     shapes = {
         "conv": (cfg.n_layers, batch, D_CONV - 1, conv_dim),
         "ssm": (cfg.n_layers, batch, cfg.ssm_heads, cfg.ssm_headdim,

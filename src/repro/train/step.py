@@ -6,6 +6,13 @@ exactly this.  Gradient accumulation (microbatching) runs as a
 ``lax.scan`` over global-batch splits; buffers are donated.  The forward,
 loss and backward run under the name scope ``train.loss`` and the AdamW
 update under ``train.optimizer``, so a profile's op lines split the step.
+A model with expert layers (``loss_and_stats``) returns their routing
+counts beside ``loss`` and ``grad_norm`` (``moe_pairs``: pairs per held
+expert of each expert layer, summed over microbatches, and ``moe_load``
+where a selection bias is balanced on it); :func:`record_routing` hands
+them to the profiler session's counters.  A model with state that no
+gradient trains (``update_state``: Nemotron-H's selection biases) sets it
+after the optimizer's step, from the step's counts.
 """
 
 from __future__ import annotations
@@ -15,6 +22,7 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import get_model
@@ -74,6 +82,13 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, global_batch: int,
     b_spec = batch_specs(cfg, batch_abs, rules, mesh)
     b_shard = {k: NamedSharding(mesh, v) for k, v in b_spec.items()}
 
+    def loss_and_stats(params, batch):
+        if hasattr(model, "loss_and_stats"):
+            return model.loss_and_stats(params, batch, cfg)
+        return model.loss_fn(params, batch, cfg), {}
+
+    grad_fn = jax.value_and_grad(loss_and_stats, has_aux=True)
+
     def train_step(params, opt_state, batch):
       with activation_rules(mesh, rules):
         if tc.microbatches > 1:
@@ -86,35 +101,38 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, global_batch: int,
             def body(carry, i):
                 acc = carry
                 with jax.named_scope("train.loss"):
-                    loss, g = jax.value_and_grad(model.loss_fn)(
-                        params, micro(i), cfg)
-                return jax.tree.map(jnp.add, acc,
-                                    {"g": g, "loss": loss}), None
+                    (loss, stats), g = grad_fn(params, micro(i))
+                return jax.tree.map(jnp.add, acc, {"g": g, "loss": loss,
+                                                   "stats": stats}), None
 
             zero = {"g": jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), params),
-                "loss": jnp.zeros((), jnp.float32)}
+                "loss": jnp.zeros((), jnp.float32),
+                "stats": jax.tree.map(
+                    lambda a: jnp.zeros(a.shape, a.dtype),
+                    jax.eval_shape(loss_and_stats, params, micro(0))[1])}
             acc, _ = jax.lax.scan(body, zero,
                                   jnp.arange(tc.microbatches))
             grads = jax.tree.map(lambda g: g / tc.microbatches, acc["g"])
-            loss = acc["loss"] / tc.microbatches
+            loss, stats = acc["loss"] / tc.microbatches, acc["stats"]
         else:
             with jax.named_scope("train.loss"):
-                loss, grads = jax.value_and_grad(model.loss_fn)(
-                    params, batch, cfg)
+                (loss, stats), grads = grad_fn(params, batch)
         with jax.named_scope("train.optimizer"):
             new_params, new_opt = apply_updates(params, grads, opt_state,
                                                 tc.adamw)
+            if hasattr(model, "update_state"):
+                new_params = model.update_state(params, new_params, stats,
+                                                cfg)
         metrics = {"loss": loss,
                    "grad_norm": jnp.sqrt(sum(
                        jnp.sum(jnp.square(g.astype(jnp.float32)))
-                       for g in jax.tree.leaves(grads)))}
+                       for g in jax.tree.leaves(grads))), **stats}
         return new_params, new_opt, metrics
 
     in_shardings = (p_shard, opt_shard, b_shard)
-    out_shardings = (p_shard, opt_shard,
-                     {"loss": NamedSharding(mesh, P()),
-                      "grad_norm": NamedSharding(mesh, P())})
+    # one replicated sharding for every metric, the routing counts too
+    out_shardings = (p_shard, opt_shard, NamedSharding(mesh, P()))
     abstract_inputs = (params_abs, opt_abs, batch_abs)
     return train_step, in_shardings, out_shardings, abstract_inputs
 
@@ -197,3 +215,21 @@ def build_decode_step(cfg: ModelConfig, mesh: Mesh, global_batch: int,
     out_shardings = (logits_shard, c_shard)
     abstract_inputs = (params_abs, cache_abs, lengths_abs, tokens_abs)
     return serve_step, in_shardings, out_shardings, abstract_inputs
+
+
+def record_routing(metrics) -> None:
+    """While a profiler session is on, add a step's routing counts to the
+    counters of ``repro.obs.spans``: ``moe.pairs_max`` (the most pairs any
+    held expert got, summed over the expert layers), ``moe.pairs`` (all
+    pairs routed to held experts), ``moe.layer_steps`` (expert layers) and
+    ``moe.held_slots`` (expert layers times held experts).  Reads the
+    counts back from the device; off, or for a model without experts, it
+    does nothing."""
+    from ..obs import spans
+    if "moe_pairs" not in metrics or not spans.on():
+        return
+    pairs = np.asarray(metrics["moe_pairs"])        # (expert layers, held)
+    spans.count("moe.pairs_max", int(pairs.max(axis=1).sum()))
+    spans.count("moe.pairs", int(pairs.sum()))
+    spans.count("moe.layer_steps", pairs.shape[0])
+    spans.count("moe.held_slots", pairs.size)

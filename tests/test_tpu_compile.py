@@ -15,7 +15,9 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.gc_compact import gather_page_blocks, move_pages
+from repro.kernels.ops import gmm
 from repro.kernels.paged_attention import paged_attention
+from repro.kernels.train_attention import causal_attention
 
 
 @pytest.fixture(scope="module")
@@ -82,3 +84,70 @@ def test_flash_attention_compiles_for_v5e(one_chip):
     bf16 = jnp.bfloat16
     _compile(flash_attention, one_chip, ((1, 2048, 16, 128), bf16),
              ((1, 2048, 16, 128), bf16), ((1, 2048, 16, 128), bf16))
+
+
+@pytest.mark.parametrize("k,n", [(2688, 1856), (1856, 2688)])
+def test_grouped_matmul_compiles_for_v5e(one_chip, k, n):
+    # the nemotron3-nano cell's expert projections, forward and backward:
+    # 4 x 8192 tokens x top-6 sorted pairs, 8 held experts of 2688 x 1856
+    # (up) and 1856 x 2688 (down), a ninth group for the other pairs
+    def loss(x, w, sizes):
+        return jnp.sum(gmm(x, w, sizes, interpret=False)
+                       .astype(jnp.float32))
+    _compile(jax.jit(jax.grad(loss, argnums=(0, 1))), one_chip,
+             ((4 * 8192 * 6, k), jnp.bfloat16), ((8, k, n), jnp.bfloat16),
+             ((9,), jnp.int32))
+
+
+def test_training_attention_compiles_for_v5e(one_chip):
+    # the cell's attention layer at S 8192, 32 query and 2 KV heads of
+    # 128, forward and backward (the splash kernels' fused backward)
+    def loss(q, k, v):
+        return jnp.sum(causal_attention(q, k, v, interpret=False)
+                       .astype(jnp.float32))
+    bf16 = jnp.bfloat16
+    _compile(jax.jit(jax.grad(loss, argnums=(0, 1, 2))), one_chip,
+             ((1, 8192, 32, 128), bf16), ((1, 8192, 2, 128), bf16),
+             ((1, 8192, 2, 128), bf16))
+
+
+@pytest.mark.parametrize("kernel", ["attention", "experts"])
+def test_kernels_run_per_chip_on_a_v5e_mesh(one_chip, monkeypatch, kernel):
+    # on a (4, 1) data mesh each chip runs the Pallas kernel on its own
+    # rows, forward and backward, with no operand gathered from the others:
+    # olmo1b-train-dp4's attention (64 rows of 2048, 16 heads of 128) and a
+    # granite-sized expert layer (40 experts of 1536 x 512, top-8)
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.launch.mesh import _mesh
+    from repro.models import modules, moe
+    from repro.parallel.ctx import activation_rules
+    from repro.parallel.sharding import default_rules
+    monkeypatch.setattr(ops, "interpret_mode", lambda interpret=None: False)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = _mesh((4, 1), ("data", "model"), topo.devices)
+    rows, whole = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    bf16 = jnp.bfloat16
+    if kernel == "attention":
+        fn, diff = modules._kernel_attention, (0, 1, 2)
+        args = [((64, 2048, 16, 128), bf16, rows)] * 3
+    else:
+        cfg = get_config("granite_moe_3b_a800m")
+        names = sorted(moe.specs(cfg))
+        fn, diff = (lambda x, *ws: moe.moe(dict(zip(names, ws)), x, cfg)[0],
+                    tuple(range(len(names) + 1)))
+        args = [((8, 2048, cfg.d_model), bf16, rows)] + [
+            (moe.specs(cfg)[k].shape, jnp.float32, whole) for k in names]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d, sh in args]
+
+    def loss(*a):
+        with activation_rules(mesh, default_rules(mesh)):
+            return jnp.sum(fn(*a).astype(jnp.float32))
+    hlo = jax.jit(jax.grad(loss, argnums=diff)).lower(
+        *args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert "all-gather" not in hlo
