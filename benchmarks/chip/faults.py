@@ -28,10 +28,11 @@ def answer_altered():
 
 
 def compaction_misplaced():
-    """Serving: each compaction leaves the first pages of two sequences
-    swapped, their tables unchanged: prompt rows end up under another
-    sequence, while every row a decode step wrote stays in place."""
-    import numpy as np
+    """Serving: each compaction copies the first page of the oldest
+    sequence over the first page of every other, their tables unchanged:
+    prompt rows of one sequence end up under the others, while every row a
+    decode step wrote stays in place.  (A swap of two pages would undo
+    itself at the next compaction of the same two sequences.)"""
     from repro.serving.kvcache import PagedKVCache
     compact = PagedKVCache.compact
 
@@ -39,9 +40,8 @@ def compaction_misplaced():
         n = compact(self)
         firsts = [t[0] for t in self.tables.values() if t]
         if len(firsts) >= 2:
-            perm = np.arange(self.pc.n_pages)
-            perm[firsts[0]], perm[firsts[1]] = firsts[1], firsts[0]
-            self.pool = self.pool[:, :, jnp.asarray(perm)]
+            self.pool = self.pool.at[:, :, jnp.asarray(firsts[1:])].set(
+                self.pool[:, :, firsts[0]][:, :, None])
         return n
     PagedKVCache.compact = misplaced
 

@@ -1,8 +1,9 @@
 """Host time of one page GC compaction, in ms: the total time of the
-program's span ``serve.cache.compact`` (``PagedKVCache.compact``: the plan,
-the layer-0 kernel call synced by its page index, the whole-pool permute's
-dispatch, the table rewrite) over its count, from ``repro.obs.spans`` as
-the traced window recorded them.  Moves ``itl_p95_ms``."""
+program's span ``serve.cache.compact`` (``PagedKVCache.compact``: the plan
+of the stable pack in NumPy, the dispatch of ``gc_compact.move_pages``
+over the pages that move, unsynced, and the rewrite of the page tables and
+the free list) over its count, from ``repro.obs.spans`` as the traced
+window recorded them.  Moves ``itl_p95_ms``."""
 
 
 def read(run):

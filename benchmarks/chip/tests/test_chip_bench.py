@@ -132,6 +132,52 @@ def test_every_seed_offers_the_same_lengths():
     assert all(32 <= p <= 2048 and 2 <= o <= 256 for p, o in a)
 
 
+# -- page schedule ---------------------------------------------------------
+
+def _mix(name):
+    return json.loads((HERE / "traffic" / f"{name}.json").read_text())
+
+
+@pytest.mark.parametrize("sizes", ["full", "smoke"])
+def test_longctx_lengths_fit_their_share_of_the_pool(sizes):
+    """No longctx sequence can outgrow ``n_pages / max_batch`` pages: the
+    other sequences never hold more than their share, so every admission
+    fits and no ``append_token`` finds the pool empty, at any pace."""
+    mix = _mix("longctx")
+    if sizes == "smoke":
+        mix = dict(mix, **mix["smoke"])
+    pool = mix["pool"]
+    share = pool["n_pages"] * pool["page_size"] // mix["batching"]["max_batch"]
+    assert mix["prompt"]["max"] + mix["output"]["max"] <= share
+
+
+@pytest.mark.parametrize("name,seed,iterations", [
+    ("longctx", 1, 20000), ("longctx", 99, 20000),
+    ("longctx", 7400000137, 20000),
+    ("churn", 1, 4000), ("churn", 7400000109, 4000)])
+def test_page_schedule_never_starves(name, seed, iterations):
+    """The driver's pool, loop and backlog at full pool size, driven
+    through ``ServeLoop.step`` with a decode step that does nothing, far
+    past what a window reaches at any pace (20000 iterations is 1 ms an
+    iteration over a 20 s window): no allocation ever fails.  Page GC
+    never runs in longctx, and all eight slots decode every iteration."""
+    from repro.models.config import ModelConfig
+    serve = run.load_module(HERE / "drivers" / "serve_paged.py")
+    mix = _mix(name)
+    cfg = ModelConfig(name="pages", family="dense", n_layers=1, d_model=8,
+                      n_heads=1, kv_heads=1)
+    sv = serve._Serve(cfg, mix, seed, None, None, record=False)
+    batches = []
+    for _ in range(iterations):
+        sv.top_up()
+        sv.loop.step(lambda ids: batches.append(len(ids)))
+    assert sv.cache.alloc_failures == 0
+    assert len(sv.loop.done) > 0
+    if name == "longctx":
+        assert sv.loop.compaction_steps == 0
+        assert set(batches) == {mix["batching"]["max_batch"]}
+
+
 # -- the harness -----------------------------------------------------------
 
 def test_manifest_names_files():
@@ -224,5 +270,19 @@ def test_fault_is_not_correct(cell, fault, monkeypatch):
         monkeypatch.setattr(PagedKVCache, name, getattr(PagedKVCache, name))
     monkeypatch.setattr(step_mod, "build_train_step",
                         step_mod.build_train_step)
-    r = _rehearse(cell, patch=getattr(faults, fault))
+    if fault != "compaction_misplaced":
+        r = _rehearse(cell, patch=getattr(faults, fault))
+        assert not r["correct"], r["checks"]
+        return
+    # a misplaced page shows only where the window compacts, and a busy
+    # CPU may fit too few iterations into a second for any sequence to
+    # finish: lengthen the window until it holds a compaction
+    compact = PagedKVCache.compact
+    for seconds in (1.0, 3.0, 9.0):
+        PagedKVCache.compact = compact
+        r = _rehearse(cell, trace=True, patch=faults.compaction_misplaced,
+                      seconds=seconds)
+        if r["metrics"].get("sched.compact_share", {}).get("value"):
+            break
+    assert r["metrics"]["sched.compact_share"]["value"] > 0, seconds
     assert not r["correct"], r["checks"]

@@ -22,7 +22,9 @@ sys.path.insert(0, str(REPO / "src"))
 
 import run  # noqa: E402
 
-# metric -> (the serve cells that report it, lowest value, highest value)
+# metric -> (the serve cells that report it, lowest value, highest value);
+# a reading must lie above the lowest value, or at it for the metrics in
+# AT_LOWEST
 METRICS = {
     "sched.host_ms": (("olmo1b-serve-churn", "olmo1b-serve-longctx"),
                       0.0, math.inf),
@@ -33,6 +35,9 @@ METRICS = {
     "sched.batch_occupancy": (("olmo1b-serve-churn", "olmo1b-serve-longctx"),
                               0.0, 100.0),
 }
+# a compaction that moves only the pages it must copies exactly one page
+# per page relocated
+AT_LOWEST = {"cache.compact_copy_ratio"}
 SERVE = ["olmo1b-serve-churn", "olmo1b-serve-longctx"]
 
 
@@ -60,13 +65,15 @@ def test_traced_rehearsal_reports_each_metric_in_range(rehearsal):
             assert name not in r["metrics"]
             continue
         value = r["metrics"][name]["value"]
-        assert value is not None and lo < value <= hi, (cell, name, value)
+        assert value is not None, (cell, name)
+        above = lo <= value if name in AT_LOWEST else lo < value
+        assert above and value <= hi, (cell, name, value)
 
 
 def test_longctx_keeps_every_slot_full(rehearsal):
     cell, r = rehearsal
     occupancy = r["metrics"]["sched.batch_occupancy"]["value"]
-    if cell == "olmo1b-serve-longctx":      # none finishes, none waits
+    if cell == "olmo1b-serve-longctx":      # none waits: every admission fits
         assert occupancy == 100.0
     else:
         assert 0.0 < occupancy <= 100.0
