@@ -15,6 +15,16 @@ Compaction keeps live pages dense at the front of the pool so admission
 of long prompts never fails on fragmentation; the scheduler triggers it
 with the paper's pressure arithmetic (serving/scheduler.py).
 
+The pool is updated in place: a decoded token's K/V row is written by
+``kv_scatter_row`` and a compaction's moves by ``gc_compact.move_pages``,
+both programs that take the pool donated.  An array taken from
+``PagedKVCache.pool`` before ``write_token_kv`` or ``compact`` is deleted
+by it; read the pool again afterwards.
+
+Page tables handed to the attention kernel are padded to a multiple of
+``PAGE_TABLE_BUCKET`` pages with unmapped (−1) columns, which the kernel
+masks, so one attention program serves a range of sequence lengths.
+
 While a profiler session is on, the write, attend and compact paths
 record ``serve.cache.*`` spans and ``cache.*`` counters through
 :mod:`repro.obs.spans`.
@@ -23,17 +33,34 @@ record ``serve.cache.*`` spans and ``cache.*`` counters through
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..kernels import ops
 from ..kernels.gc_compact import move_pages
 from ..models.config import ModelConfig
 from ..obs import spans
+
+PAGE_TABLE_BUCKET = 16     # page-table widths are multiples of this
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def kv_scatter_row(pool, where, k, v):
+    """pool: (L, 2, P, page, kvH, hd), donated; where: (3,) int32, the
+    layer, page and slot; k, v: (kvH, hd).  Returns the pool with that row
+    of the layer's K and V planes set to ``k`` and ``v``, cast to the pool's
+    dtype, in place (the output aliases the pool).  The position is traced,
+    so one program serves every layer, page and slot."""
+    row = jnp.stack([k, v]).astype(pool.dtype)[None, :, None, None]
+    zero = jnp.zeros((), jnp.int32)
+    return lax.dynamic_update_slice(
+        pool, row, (where[0], zero, where[1], where[2], zero, zero))
 
 
 @dataclasses.dataclass
@@ -117,29 +144,37 @@ class PagedKVCache:
     # -- device-side views -------------------------------------------------
     def page_table_array(self, seq_ids: List[int]) -> Tuple[jax.Array,
                                                             jax.Array]:
+        """The sequences' page tables, padded with −1 to a width that is a
+        multiple of ``PAGE_TABLE_BUCKET`` pages (at most ``n_pages``), and
+        their lengths."""
         with spans.span("serve.cache.page_table"):
             max_pages = max((len(self.tables[s]) for s in seq_ids),
                             default=1)
-            pt = np.full((len(seq_ids), max_pages), -1, np.int32)
+            width = min(-(-max_pages // PAGE_TABLE_BUCKET)
+                        * PAGE_TABLE_BUCKET, self.pc.n_pages)
+            pt = np.full((len(seq_ids), width), -1, np.int32)
             ln = np.zeros((len(seq_ids),), np.int32)
             for i, s in enumerate(seq_ids):
                 pages = self.tables[s]
                 pt[i, :len(pages)] = pages
                 ln[i] = self.lengths[s]
+            spans.count("cache.page_table.pad_pages",
+                        (width - max_pages) * len(seq_ids))
             return jnp.asarray(pt), jnp.asarray(ln)
 
     def write_token_kv(self, layer: int, seq_id: int, k, v) -> None:
-        """Write one token's K/V (kvH, hd) into the page pool."""
+        """Write one token's K/V (kvH, hd) into the page pool, at the
+        sequence's last position, by one program that updates the pool in
+        place (``kv_scatter_row``).  The pool is donated to it: an array
+        taken from ``self.pool`` before the call is deleted."""
         pos = self.lengths[seq_id] - 1
         page = self.tables[seq_id][pos // self.pc.page_size]
         slot = pos % self.pc.page_size
         on = spans.on()
         with (spans.Span("serve.cache.kv_write", seq=seq_id, page=page,
                          slot=slot) if on else spans.OFF):
-            self.pool = self.pool.at[layer, 0, page, slot].set(
-                k.astype(self.pool.dtype))
-            self.pool = self.pool.at[layer, 1, page, slot].set(
-                v.astype(self.pool.dtype))
+            self.pool = kv_scatter_row(
+                self.pool, np.array((layer, page, slot), np.int32), k, v)
         if on:
             spans.count("cache.kv_rows_written", 2)
 

@@ -1,7 +1,10 @@
-"""Serving: paged cache correctness (attend == dense reference), GC
-compaction preserves live data in every plane as a stable pack, one mover
-program per bucket of compaction sizes, scheduler completes all
-requests."""
+"""Serving: paged cache correctness (attend == dense reference), K/V row
+writes in place in the donated pool through one program, bucketed
+page-table widths that leave attention unchanged, GC compaction preserves
+live data in every plane as a stable pack, one mover program per bucket of
+compaction sizes, scheduler completes all requests."""
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -10,8 +13,11 @@ import pytest
 
 from repro.configs import get_config
 from repro.kernels.gc_compact import move_pages
+from repro.kernels.paged_attention import paged_attention
+from repro.obs import spans
 from repro.serving import (PagedCacheConfig, PagedKVCache, Request,
                            ServeConfig, ServeLoop)
+from repro.serving.kvcache import PAGE_TABLE_BUCKET, kv_scatter_row
 
 
 def _mk(n_pages=32, page_size=4):
@@ -72,6 +78,106 @@ def test_compaction_preserves_live_kv():
     np.testing.assert_allclose(np.asarray(after), np.asarray(before),
                                atol=1e-6)
     assert cache.fragmentation() <= frag_before
+
+
+def _filled(n_pages=12, page_size=4):
+    """A cache whose pool is filled from a seed, so that a write that
+    lands anywhere else shows."""
+    cfg, cache = _mk(n_pages=n_pages, page_size=page_size)
+    cache.pool = jax.random.normal(jax.random.PRNGKey(9), cache.pool.shape,
+                                   jnp.float32).astype(cache.pool.dtype)
+    return cfg, cache
+
+
+def test_kv_writes_match_a_numpy_model_row_for_row():
+    """Writes across layers, pages, slots and both planes leave the pool
+    equal to the same writes made on a NumPy copy, every other row as it
+    was."""
+    cfg, cache = _filled()
+    want = np.asarray(cache.pool).copy()
+    rng = np.random.default_rng(4)
+    for sid, prompt in ((1, 3), (2, 0), (3, 6)):
+        assert cache.add_sequence(sid, prompt)
+    for t in range(11):
+        for sid in (1, 2, 3):
+            assert cache.append_token(sid)
+            pos = cache.lengths[sid] - 1
+            page = cache.tables[sid][pos // cache.pc.page_size]
+            slot = pos % cache.pc.page_size
+            layer = (t + sid) % cfg.n_layers
+            k, v = (rng.normal(size=(cfg.kv_heads, cfg.head_dim))
+                    .astype(np.float32) for _ in range(2))
+            cache.write_token_kv(layer, sid, jnp.asarray(k), jnp.asarray(v))
+            want[layer, 0, page, slot] = k.astype(want.dtype)
+            want[layer, 1, page, slot] = v.astype(want.dtype)
+    np.testing.assert_array_equal(np.asarray(cache.pool).view(np.uint16),
+                                  want.view(np.uint16))
+
+
+def test_kv_writes_at_every_position_share_one_program():
+    kv_scatter_row.clear_cache()
+    cfg, cache = _filled()
+    assert cache.add_sequence(1, 0)
+    k = jnp.ones((cfg.kv_heads, cfg.head_dim), jnp.float32)
+    for t in range(9):                  # three pages, every slot
+        assert cache.append_token(1)
+        cache.write_token_kv(t % cfg.n_layers, 1, k * t, -k * t)
+    assert kv_scatter_row._cache_size() == 1
+
+
+def _honours_donation() -> bool:
+    x = jnp.zeros((8,))
+    jax.jit(lambda a: a + 1, donate_argnums=0)(x)
+    return x.is_deleted()
+
+
+def test_kv_write_donates_the_pool():
+    cfg, cache = _filled()
+    assert cache.add_sequence(1, 1)
+    before = cache.pool
+    k = jnp.ones((cfg.kv_heads, cfg.head_dim), jnp.float32)
+    cache.write_token_kv(0, 1, k, k)
+    assert cache.pool is not before
+    if _honours_donation():
+        assert before.is_deleted()
+
+
+@pytest.mark.parametrize("n_pages", [40, 64])
+def test_bucketed_page_table_leaves_attention_unchanged(n_pages, tmp_path):
+    """``attend`` over the bucketed table equals the kernel over the exact
+    one, bit for bit; every page count from 1 to ``n_pages`` takes one of
+    at most ceil(n_pages / 16) widths; the padding is counted."""
+    cfg, cache = _filled(n_pages=n_pages, page_size=2)
+    for sid, pages in ((0, 3), (1, 17), (2, 5)):
+        assert cache.add_sequence(sid, 2 * pages - sid % 2)
+    seqs = [0, 1, 2]
+    q = jax.random.normal(jax.random.PRNGKey(3),
+                          (3, cfg.n_heads, cfg.head_dim), jnp.float32)
+    pt, ln = cache.page_table_array(seqs)
+    assert pt.shape == (3, 2 * PAGE_TABLE_BUCKET)
+    exact = np.full((3, 17), -1, np.int32)
+    for i, s in enumerate(seqs):
+        exact[i, :len(cache.tables[s])] = cache.tables[s]
+    want = paged_attention(q, cache.pool[0, 0], cache.pool[0, 1],
+                           jnp.asarray(exact), ln, interpret=True)
+    got = cache.attend(0, seqs, q)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    for s in seqs:
+        cache.finish_sequence(s)
+    widths, padded = set(), 0
+    with jax.profiler.trace(str(tmp_path)):
+        for n in range(1, n_pages + 1):
+            assert cache.add_sequence(n, 2 * n)
+            width = cache.page_table_array([n])[0].shape[1]
+            assert n <= width <= n_pages
+            assert width % PAGE_TABLE_BUCKET == 0 or width == n_pages
+            widths.add(width)
+            padded += width - n
+            cache.finish_sequence(n)
+        counted = spans.snapshot()["counters"]["cache.page_table.pad_pages"]
+    assert len(widths) <= math.ceil(n_pages / PAGE_TABLE_BUCKET)
+    assert counted == padded > 0
 
 
 def _holed(page_counts, finished, use_pallas=True):

@@ -18,6 +18,7 @@ from repro.kernels.gc_compact import gather_page_blocks, move_pages
 from repro.kernels.ops import gmm
 from repro.kernels.paged_attention import paged_attention
 from repro.kernels.train_attention import causal_attention
+from repro.serving.kvcache import kv_scatter_row
 
 
 @pytest.fixture(scope="module")
@@ -151,3 +152,15 @@ def test_kernels_run_per_chip_on_a_v5e_mesh(one_chip, monkeypatch, kernel):
         *args).compile().as_text()
     assert "tpu_custom_call" in hlo
     assert "all-gather" not in hlo
+
+
+def test_kv_scatter_row_compiles_for_v5e_in_place(one_chip):
+    # one token's K and V row into the whole olmo-1b pool: the output is
+    # the donated pool, and nothing the size of a plane is made
+    pool = ((16, 2, 1024, 16, 16, 128), jnp.bfloat16)
+    row = ((16, 128), jnp.float32)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in (pool, ((3,), jnp.int32), row, row)]
+    mem = kv_scatter_row.lower(*args).compile().memory_analysis()
+    assert mem.alias_size_in_bytes == 2 ** 31
+    assert mem.temp_size_in_bytes < 2 ** 20
